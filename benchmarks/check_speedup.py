@@ -34,9 +34,7 @@ To stay machine-independent, the gates compare *normalized* numbers:
   solver must be >= 3x faster (acceptance bar) and must not regress more
   than 2x against the recorded speedup ratio — both are ratios of
   same-process wall-clocks, so slower CI hardware cancels out.  The
-  gate also re-checks decision equality job by job.  When jax is not
-  importable the jit gate is skipped with a notice (the committed
-  baseline documents the container result).
+  gate also re-checks decision equality job by job.
 - the commit gate (baseline_fig5_commit.json) runs the *end-to-end*
   greedy ``dp_allocation`` (pricing + wave/scan commit) over the full
   n=2048 fig5 queue under ``solver="jax"`` and under the sequential
@@ -54,8 +52,8 @@ the pricing threshold at runtime).
 
 ``--quick`` runs a seconds-scale smoke over a tiny trace: both engines
 and the HadarE backend must complete every job and agree within the
-documented quantization tolerance, and (when jax is importable) the
-batched solver must match the per-job path on small shapes.  It also
+documented quantization tolerance, and the batched solver must match
+the per-job path on small shapes.  It also
 runs the policy-comparison harness (``repro.env.compare``) over two
 baselines on a tiny fig5 trace — the emitted table must schema-validate
 and match the committed ``baseline_policy_table.json`` bit-for-bit (the
@@ -367,10 +365,6 @@ def calibrate() -> None:
     from repro.core.trace import philly_trace
     from repro.core.utility import effective_throughput
 
-    if not bs.HAS_JAX:
-        print("cannot calibrate: jax unavailable on this host")
-        raise SystemExit(2)
-
     def state(n):
         cluster = grown_cluster(n)
         jobs = philly_trace(n_jobs=n, seed=1, types=cluster.gpu_types)
@@ -498,52 +492,47 @@ def quick_smoke() -> None:
                f"{lat.count} consults)")
 
     # jit smoke: compile on small shapes, decisions must match the
-    # per-job path exactly (seconds on CPU; skipped without jax)
-    from repro.core.batch_solver import HAS_JAX
-    jit_msg = "jit skipped (no jax)"
-    if HAS_JAX:
-        jit = measure_jit(n_jobs=32, repeats=1)
-        assert jit["mismatches"] == 0, \
-            f"jit smoke: {jit['mismatches']} decision mismatches"
-        jit_msg = f"jit n=32 match ({jit['jit_s']*1e3:.0f}ms/call)"
+    # per-job path exactly (seconds on CPU)
+    jit = measure_jit(n_jobs=32, repeats=1)
+    assert jit["mismatches"] == 0, \
+        f"jit smoke: {jit['mismatches']} decision mismatches"
+    jit_msg = f"jit n=32 match ({jit['jit_s']*1e3:.0f}ms/call)"
 
     # wave-commit smoke: the forced-jax greedy pass (wave partitioner +
     # device scan) must match the sequential NumPy loop decision for
     # decision, and report its waves through repro.obs
-    wave_msg = "wave skipped (no jax)"
-    if HAS_JAX:
-        from benchmarks.fig5_scalability import grown_cluster
-        from repro.core.dp import dp_allocation
-        from repro.core.pricing import PriceState
-        from repro.core.utility import effective_throughput
-        wcluster = grown_cluster(64)
-        wjobs = philly_trace(n_jobs=64, seed=3, types=wcluster.gpu_types)
-        sel = {}
-        waves = 0
-        for sv in ("numpy", "jax"):
-            ps = PriceState(wcluster, wjobs, 7 * 24 * 3600.0,
-                            effective_throughput, 0.0)
-            if sv == "jax":
-                with obs.session(trace=False, decisions=False) as wob:
-                    sel[sv] = dp_allocation(wjobs, None, ps, 0.0,
-                                            effective_throughput,
-                                            max_exact=0, solver=sv)
-                waves = wob.metrics.summary()["counters"].get(
-                    "solver.commit_waves", 0)
-                assert waves >= 1, "wave partitioner emitted no waves"
-            else:
+    from benchmarks.fig5_scalability import grown_cluster
+    from repro.core.dp import dp_allocation
+    from repro.core.pricing import PriceState
+    from repro.core.utility import effective_throughput
+    wcluster = grown_cluster(64)
+    wjobs = philly_trace(n_jobs=64, seed=3, types=wcluster.gpu_types)
+    sel = {}
+    waves = 0
+    for sv in ("numpy", "jax"):
+        ps = PriceState(wcluster, wjobs, 7 * 24 * 3600.0,
+                        effective_throughput, 0.0)
+        if sv == "jax":
+            with obs.session(trace=False, decisions=False) as wob:
                 sel[sv] = dp_allocation(wjobs, None, ps, 0.0,
                                         effective_throughput,
                                         max_exact=0, solver=sv)
-        assert set(sel["numpy"]) == set(sel["jax"]), \
-            "wave smoke: selections diverged"
-        for k, a in sel["numpy"].items():
-            b = sel["jax"][k]
-            assert (a.alloc, a.cost, a.payoff, a.rate) \
-                == (b.alloc, b.cost, b.payoff, b.rate), \
-                f"wave smoke: job {k} decision diverged"
-        wave_msg = (f"wave commit match (n=64, {waves} waves, "
-                    f"{len(sel['jax'])} selected)")
+            waves = wob.metrics.summary()["counters"].get(
+                "solver.commit_waves", 0)
+            assert waves >= 1, "wave partitioner emitted no waves"
+        else:
+            sel[sv] = dp_allocation(wjobs, None, ps, 0.0,
+                                    effective_throughput,
+                                    max_exact=0, solver=sv)
+    assert set(sel["numpy"]) == set(sel["jax"]), \
+        "wave smoke: selections diverged"
+    for k, a in sel["numpy"].items():
+        b = sel["jax"][k]
+        assert (a.alloc, a.cost, a.payoff, a.rate) \
+            == (b.alloc, b.cost, b.payoff, b.rate), \
+            f"wave smoke: job {k} decision diverged"
+    wave_msg = (f"wave commit match (n=64, {waves} waves, "
+                f"{len(sel['jax'])} selected)")
 
     # compare-harness smoke: two policies over a tiny trace must emit a
     # schema-valid table whose quality metrics match the committed
@@ -605,14 +594,12 @@ def main():
         print(f"no baseline at {BASELINE}; run with --record first")
         raise SystemExit(2)
 
-    from repro.core.batch_solver import HAS_JAX
-
     current = measure()
     latency = measure_latency()
     event = measure_event()
     faults = measure_event_faults()
-    jit = measure_jit() if HAS_JAX else None
-    commit = measure_commit() if HAS_JAX else None
+    jit = measure_jit()
+    commit = measure_commit()
     if args.record:
         with open(BASELINE, "w") as f:
             json.dump({"n_jobs": N_JOBS, **current, "latency": latency},
@@ -621,12 +608,10 @@ def main():
             json.dump(event, f, indent=1)
         with open(FAULT_BASELINE, "w") as f:
             json.dump(faults, f, indent=1)
-        if jit is not None:
-            with open(JIT_BASELINE, "w") as f:
-                json.dump(jit, f, indent=1)
-        if commit is not None:
-            with open(COMMIT_BASELINE, "w") as f:
-                json.dump(commit, f, indent=1)
+        with open(JIT_BASELINE, "w") as f:
+            json.dump(jit, f, indent=1)
+        with open(COMMIT_BASELINE, "w") as f:
+            json.dump(commit, f, indent=1)
         with open(POLICY_BASELINE, "w") as f:
             json.dump(measure_policy_table(), f, indent=1, sort_keys=True)
             f.write("\n")
@@ -724,69 +709,59 @@ def main():
               f"run with --record to add one")
 
     # ---- jit-batched solver gate ----------------------------------------
-    if jit is None:
-        print("jit gate skipped: jax unavailable on this host "
-              f"(committed baseline at {JIT_BASELINE} documents the "
-              f"container result)")
+    print(f"jit solver: batched {jit['jit_s']:.3f}s vs per-job numpy "
+          f"{jit['numpy_s']:.3f}s at n={jit['n_jobs']} "
+          f"({jit['speedup']:.1f}x, {jit['mismatches']} mismatches)")
+    if jit["mismatches"]:
+        print("FAIL: jit solver decisions diverged from the NumPy "
+              "path")
+        failed = True
+    if jit["speedup"] < JIT_MIN_SPEEDUP:
+        print(f"FAIL: jit solver speedup {jit['speedup']:.2f}x below "
+              f"the {JIT_MIN_SPEEDUP}x acceptance bar")
+        failed = True
+    if os.path.exists(JIT_BASELINE):
+        with open(JIT_BASELINE) as f:
+            jbase = json.load(f)
+        jratio = jbase["speedup"] / max(jit["speedup"], 1e-9)
+        print(f"jit speedup {jit['speedup']:.1f}x vs baseline "
+              f"{jbase['speedup']:.1f}x — regression ratio "
+              f"{jratio:.2f}x (margin {MAX_REGRESSION}x)")
+        if jratio > MAX_REGRESSION:
+            print(f"FAIL: jit solver advantage regressed "
+                  f">{MAX_REGRESSION}x vs baseline")
+            failed = True
     else:
-        print(f"jit solver: batched {jit['jit_s']:.3f}s vs per-job numpy "
-              f"{jit['numpy_s']:.3f}s at n={jit['n_jobs']} "
-              f"({jit['speedup']:.1f}x, {jit['mismatches']} mismatches)")
-        if jit["mismatches"]:
-            print("FAIL: jit solver decisions diverged from the NumPy "
-                  "path")
-            failed = True
-        if jit["speedup"] < JIT_MIN_SPEEDUP:
-            print(f"FAIL: jit solver speedup {jit['speedup']:.2f}x below "
-                  f"the {JIT_MIN_SPEEDUP}x acceptance bar")
-            failed = True
-        if os.path.exists(JIT_BASELINE):
-            with open(JIT_BASELINE) as f:
-                jbase = json.load(f)
-            jratio = jbase["speedup"] / max(jit["speedup"], 1e-9)
-            print(f"jit speedup {jit['speedup']:.1f}x vs baseline "
-                  f"{jbase['speedup']:.1f}x — regression ratio "
-                  f"{jratio:.2f}x (margin {MAX_REGRESSION}x)")
-            if jratio > MAX_REGRESSION:
-                print(f"FAIL: jit solver advantage regressed "
-                      f">{MAX_REGRESSION}x vs baseline")
-                failed = True
-        else:
-            print(f"no jit baseline at {JIT_BASELINE}; "
-                  f"run with --record to add one")
+        print(f"no jit baseline at {JIT_BASELINE}; "
+              f"run with --record to add one")
 
     # ---- end-to-end greedy commit gate ----------------------------------
-    if commit is None:
-        print("commit gate skipped: jax unavailable on this host "
-              f"(committed baseline at {COMMIT_BASELINE} documents the "
-              f"container result)")
+    print(f"greedy commit: jax {commit['jax_s']:.3f}s vs numpy loop "
+          f"{commit['numpy_s']:.3f}s at n={commit['n_jobs']} "
+          f"({commit['speedup']:.2f}x, {commit['selected']} selected,"
+          f" {commit['mismatches']} mismatches)")
+    if commit["mismatches"]:
+        print("FAIL: device commit decisions diverged from the "
+              "NumPy oracle")
+        failed = True
+    if commit["speedup"] < COMMIT_MIN_SPEEDUP:
+        print(f"FAIL: commit speedup {commit['speedup']:.2f}x below "
+              f"the {COMMIT_MIN_SPEEDUP}x acceptance bar")
+        failed = True
+    if os.path.exists(COMMIT_BASELINE):
+        with open(COMMIT_BASELINE) as f:
+            cbase = json.load(f)
+        cratio = cbase["speedup"] / max(commit["speedup"], 1e-9)
+        print(f"commit speedup {commit['speedup']:.2f}x vs baseline "
+              f"{cbase['speedup']:.2f}x — regression ratio "
+              f"{cratio:.2f}x (margin {MAX_REGRESSION}x)")
+        if cratio > MAX_REGRESSION:
+            print(f"FAIL: commit advantage regressed "
+                  f">{MAX_REGRESSION}x vs baseline")
+            failed = True
     else:
-        print(f"greedy commit: jax {commit['jax_s']:.3f}s vs numpy loop "
-              f"{commit['numpy_s']:.3f}s at n={commit['n_jobs']} "
-              f"({commit['speedup']:.2f}x, {commit['selected']} selected,"
-              f" {commit['mismatches']} mismatches)")
-        if commit["mismatches"]:
-            print("FAIL: device commit decisions diverged from the "
-                  "NumPy oracle")
-            failed = True
-        if commit["speedup"] < COMMIT_MIN_SPEEDUP:
-            print(f"FAIL: commit speedup {commit['speedup']:.2f}x below "
-                  f"the {COMMIT_MIN_SPEEDUP}x acceptance bar")
-            failed = True
-        if os.path.exists(COMMIT_BASELINE):
-            with open(COMMIT_BASELINE) as f:
-                cbase = json.load(f)
-            cratio = cbase["speedup"] / max(commit["speedup"], 1e-9)
-            print(f"commit speedup {commit['speedup']:.2f}x vs baseline "
-                  f"{cbase['speedup']:.2f}x — regression ratio "
-                  f"{cratio:.2f}x (margin {MAX_REGRESSION}x)")
-            if cratio > MAX_REGRESSION:
-                print(f"FAIL: commit advantage regressed "
-                      f">{MAX_REGRESSION}x vs baseline")
-                failed = True
-        else:
-            print(f"no commit baseline at {COMMIT_BASELINE}; "
-                  f"run with --record to add one")
+        print(f"no commit baseline at {COMMIT_BASELINE}; "
+              f"run with --record to add one")
 
     if failed:
         raise SystemExit(1)

@@ -35,6 +35,7 @@ from repro.core.trace import multi_cluster, philly_trace
 from repro.core.types import Cluster, Node
 from repro.sim.adapters import CountingScheduler
 from repro.sim.engine import simulate_events, simulate_rounds
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def grown_cluster(n_jobs: int) -> Cluster:
@@ -162,9 +163,8 @@ def run_steady(n_jobs: int = 48, round_len: float = 60.0, sweep=None,
     ``sweep`` (list of job counts) scales the replay to multi-thousand-job
     Philly-style workloads; curves are measured per pricing-solver
     backend in ``solvers`` and published to one JSON artifact."""
-    from repro.core.batch_solver import HAS_JAX
     if solvers is None:
-        solvers = ["numpy"] + (["jax"] if HAS_JAX else [])
+        solvers = ["numpy", "jax"]
     sizes = list(sweep) if sweep else [n_jobs]
     out = {"round_len": round_len, "sizes": sizes, "curves": {}}
     sweep_us = {}
@@ -203,8 +203,9 @@ if __name__ == "__main__":
     ap.add_argument("--solvers", nargs="+", default=None,
                     choices=["numpy", "jax", "auto"],
                     help="pricing backends to compare (default: numpy "
-                         "+ jax when available)")
+                         "+ jax)")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.steady:
         run_steady(round_len=args.round_len, sweep=args.n_jobs,
                    solvers=args.solvers)

@@ -37,6 +37,7 @@ from repro.core.trace import philly_trace, simulation_cluster
 from repro.obs.explain import explain_allocation
 from repro.sim.adapters import run as run_engine
 from repro.sim.replay import load_trace_csv
+from repro.utils.compile_cache import enable_compile_cache
 
 N_EXPLAIN = 5                   # decisions rendered under --explain
 
@@ -63,6 +64,7 @@ def main():
                     help="also run the classic heterogeneity-blind "
                          "baselines (repro.env.baselines)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cluster = simulation_cluster()
     faults = None

@@ -24,40 +24,29 @@ axis        meaning
 ==========  =============================================================
 
 Per-job inputs are gathered on the key axis via ``rank[B, M]`` (each
-job's preference rank of key m's type).  The kernel computes, batched:
+job's preference rank of key m's type).  The pricing kernel computes,
+batched, in int32 only:
 
 - consolidated candidates (line 24): per-key availability scattered into
-  (node, rank) layout, prefix sums over the rank axis, packed take
-  counts, and packing costs gathered from the *host-computed* cumulative
-  unit-price table ``cumP`` (Eq. 5 prefix sums);
-- spread candidates (lines 25-27): price/throughput ratios over the full
-  (key, unit) pool, one stable argsort per job, per-prefix eligibility
-  masks, costs, slowest-used-rank, and server counts (the communication
-  penalty's ``n_servers - 1`` term).
+  (node, rank) layout, prefix sums over the rank axis, feasibility and
+  packed take counts;
+- spread candidates (lines 25-27): over the (key, unit) pool in the
+  host's stable price/throughput sort order, each preference prefix's
+  first W eligible units (their *positions* in that order), the slowest
+  rank used, and the server count (the communication penalty's
+  ``n_servers - 1`` term).
 
-Decision fidelity: the unit-price matrix ``P``, its prefix sums, and the
-utility table ``u_tab`` (line 28's U_j) are computed on the host with the
-exact same NumPy/scalar operations as the per-job path — XLA's ``pow``
-is not bit-identical to NumPy's — so every float the sort and the
-feasibility logic consume is bitwise equal.  Candidate *selection*
-replays the reference enumeration order (per preference prefix:
-consolidated nodes in node order, then the prefix's spread candidate;
-first maximum wins), and each winner's cost/payoff is re-derived on the
-host with the reference summation order, so emitted ``Candidate``s are
-bit-identical to ``repro.core.dp._find_alloc_arrays`` — enforced against
+Decision fidelity: the device decides nothing with floats.  The
+unit-price matrix ``P``, its prefix sums, the utility table ``u_tab``
+(line 28's U_j), and every candidate's cost and payoff are computed on
+the host from the kernel's integer outputs with the reference's own
+NumPy operations and summation order.  Selection then replays the
+reference enumeration order (per preference prefix: consolidated nodes
+in node order, then the prefix's spread candidate; first maximum wins)
+on those host-exact payoffs, so emitted ``Candidate``s are bit-identical
+to ``repro.core.dp._find_alloc_arrays`` on any backend, including a TPU,
+whose float64 is emulated and not IEEE.  Enforced against
 ``tests/_seed_reference.py`` by the engine-equivalence suite.
-
-One residual caveat: the spread-candidate cost that feeds winner
-*selection* is an XLA reduction whose accumulation order can differ from
-NumPy's by last-ulp amounts (likewise the consolidated cost's sequential
-rank-axis accumulation matches ``np.sum`` only while the type count
-stays below NumPy's 8-element pairwise-summation threshold — true of
-every cluster here), so a selection flip is conceivable when two
-*different* allocations tie to within one ulp under the reference —
-structurally symmetric ties are safe (both backends compute both sides
-identically, enumeration order resolves them the same way), and the
-equivalence suites observe zero mismatches; winners' emitted fields are
-always host-exact regardless.
 """
 from __future__ import annotations
 
@@ -66,22 +55,13 @@ import json
 import os
 from typing import Dict, List, Optional, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import enable_x64
 
 from repro import obs as _obs
 from repro.core.utility import effective_throughput
-
-try:  # the container bakes in jax; degrade to the NumPy path without it
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-
-    HAS_JAX = True
-except Exception:  # pragma: no cover - exercised only on jax-less hosts
-    jax = None
-    jnp = None
-    enable_x64 = None
-    HAS_JAX = False
 
 # Default crossover points when no calibration file is present.  Queue
 # sizes below the pricing threshold stay on the per-job NumPy path under
@@ -168,13 +148,9 @@ def check_solver(solver: Optional[str]) -> str:
 
 def resolve_solver(solver: Optional[str]) -> str:
     """Map a ``solver`` flag (None/'auto'/'jax'/'numpy') to the backend
-    that will run: auto-detect prefers jax when importable."""
+    that will run: ``auto`` prefers jax."""
     mode = check_solver(solver)
-    if mode == "auto":
-        return "jax" if HAS_JAX else "numpy"
-    if mode == "jax" and not HAS_JAX:
-        raise RuntimeError("solver='jax' requested but jax is unavailable")
-    return mode
+    return "jax" if mode == "auto" else mode
 
 
 def resolve_backend(solver: Optional[str], n_jobs: int) -> str:
@@ -186,7 +162,7 @@ def resolve_backend(solver: Optional[str], n_jobs: int) -> str:
     mode = check_solver(solver)
     if mode == "auto":
         thr = solver_threshold()
-        backend = "jax" if (HAS_JAX and n_jobs >= thr) else "numpy"
+        backend = "jax" if n_jobs >= thr else "numpy"
     else:
         thr = None
         backend = resolve_solver(mode)
@@ -212,7 +188,7 @@ def use_commit(solver: Optional[str], n_jobs: int) -> bool:
     amortizes differently (one scan dispatch vs J kernel replays)."""
     mode = check_solver(solver)
     if mode == "auto":
-        return HAS_JAX and n_jobs >= commit_threshold()
+        return n_jobs >= commit_threshold()
     return resolve_solver(mode) == "jax" and n_jobs > 0
 
 
@@ -225,123 +201,102 @@ def bucket_size(n_jobs: int) -> int:
     return b
 
 
-def _build_kernel(N: int, R: int, comm_frac: float):
-    """The fused per-(cluster-geometry) kernel: vmap over the job bucket,
-    jitted once per (B, M, C) shape triple.
-
-    The pool's stable argsort arrives pre-computed from the host (NumPy's
-    batched mergesort is both faster than XLA's CPU sort and bitwise the
-    reference operation); everything downstream — feasibility prefixes,
-    packed take counts and costs, per-prefix spread eligibility, costs,
-    server counts — is fused here.  (node, rank) aggregation is a
-    batched scatter-add (exact — each output cell has at most one
-    contributing key per job), and the chosen spread units are
-    re-derived in the original (key, unit) layout from the W-th eligible
-    element's (ratio, flat-index) threshold, which is elementwise."""
-
-    def per_job(avail, P, cumP, node_row, W, Kj, rank,
-                u_tab, single_node, s_rank, s_valid, s_price, s_ratio,
-                s_flat, ratio_o):
-        M, C = P.shape
-        L = M * C
-        Wf = W
-        Wi = W.astype(jnp.int32)
-        usable = rank < Kj
-
-        # ---- consolidated (line 24): keys into (node, rank) layout -----
-        # (node, rank) cells have at most one contributing key per job, so
-        # the scatter-add is exact in any accumulation order — and O(M)
-        # instead of the dense one-hot contraction's O(N*M) per job
-        av_use = jnp.where(usable, avail, 0.0)
-        A = jnp.zeros((N, R + 1), P.dtype).at[
-            node_row, rank].add(av_use)[:, :R]
-        Apos = jnp.maximum(A, 0.0)
-        # unrolled prefix sums over the (small, static) rank axis keep the
-        # accumulation order identical to NumPy's sequential cumsum
-        raw_cols, pos_cols = [], []
-        rc = jnp.zeros((N,), P.dtype)
-        pc = jnp.zeros((N,), P.dtype)
-        for k in range(R):
-            rc = rc + A[:, k]
-            pc = pc + Apos[:, k]
-            raw_cols.append(rc)
-            pos_cols.append(pc)
-        rawcum = jnp.stack(raw_cols, axis=1)
-        poscum = jnp.stack(pos_cols, axis=1)
-        feas_any = rawcum >= Wf
-        feasible = feas_any.any(axis=1)
-        k_first = jnp.argmax(feas_any, axis=1)
-        take = jnp.clip(Wf - (poscum - Apos), 0.0, Apos)
-        j_last = jnp.argmax(poscum >= Wf, axis=1)
-
-        take_pad = jnp.concatenate([take, jnp.zeros((N, 1), P.dtype)],
-                                   axis=1)
-        t_key = take_pad[node_row, rank].astype(jnp.int32)
-        v = jnp.where(usable,
-                      jnp.take_along_axis(cumP, t_key[:, None],
-                                          axis=1)[:, 0],
-                      0.0)
-        vs = jnp.zeros((N, R + 1), P.dtype).at[node_row, rank].add(v)
-        packed_cost = vs[:, 0]
-        for k in range(1, R):
-            packed_cost = packed_cost + vs[:, k]
-        packed_payoff = u_tab[j_last] - packed_cost
-
-        # ---- spread (lines 25-27): prefix masks over the sorted pool ---
-        i_idx = jnp.arange(C)
-        valid = usable[:, None] & (i_idx[None, :] < avail[:, None])
-        flat_grid = jnp.arange(L).reshape(M, C)
-        lidx = jnp.arange(L)
-
-        ok_l, pay_l, jmax_l, nserv_l, counts_l = [], [], [], [], []
-        for k in range(1, R + 1):
-            elig = s_valid & (s_rank < k)
-            csum = jnp.cumsum(elig.astype(jnp.int32))
-            n_elig = csum[-1]
-            chosen = elig & (csum <= Wi)
-            cost2 = jnp.sum(jnp.where(chosen, s_price, 0.0))
-            jmax = jnp.max(jnp.where(chosen, s_rank, -1))
-            # chosen units, back in (key, unit) layout: everything at or
-            # below the last chosen element's (ratio, flat) sort key
-            p_last = jnp.maximum(jnp.max(jnp.where(chosen, lidx, -1)), 0)
-            tau = s_ratio[p_last]
-            fstar = s_flat[p_last]
-            elig_o = valid & (rank < k)[:, None]
-            chosen_o = elig_o & ((ratio_o < tau)
-                                 | ((ratio_o == tau)
-                                    & (flat_grid <= fstar)))
-            cnt = jnp.sum(chosen_o, axis=1, dtype=jnp.int32)
-            node_cnt = jnp.zeros((N,), jnp.int32).at[node_row].add(cnt)
-            nserv = jnp.sum((node_cnt > 0).astype(jnp.int32))
-            u_jmax = u_tab[jnp.maximum(jmax, 0)]
-            cost2 = cost2 + jnp.where(
-                nserv > 1,
-                comm_frac * jnp.maximum(u_jmax, 0.0) * (nserv - 1),
-                0.0)
-            ok_l.append((n_elig >= Wi) & jnp.logical_not(single_node)
-                        & (k <= Kj))
-            pay_l.append(u_jmax - cost2)
-            jmax_l.append(jmax)
-            nserv_l.append(nserv)
-            counts_l.append(cnt)
-
-        return (feasible, k_first, j_last, take, packed_cost,
-                packed_payoff,
-                jnp.stack(ok_l), jnp.stack(pay_l), jnp.stack(jmax_l),
-                jnp.stack(nserv_l), jnp.stack(counts_l))
-
-    return jax.jit(jax.vmap(
-        per_job, in_axes=(None, None, None, None, 0, 0, 0, 0, 0,
-                          0, 0, 0, 0, 0, 0)))
+def _consolidated(avail, node_row, rank, W, Kj, N: int, R: int):
+    """Consolidated slots of one job (line 24), in exact integers: the
+    job's usable per-key availability scattered into (node, preference
+    rank) layout — each cell has at most one key, so the scatter-add is
+    a placement — then per node the feasibility, first feasible prefix,
+    slowest rank used and packed take counts."""
+    av_use = jnp.where(rank < Kj, avail, 0)
+    A = jnp.zeros((N, R + 1), jnp.int32).at[node_row, rank].add(
+        av_use)[:, :R]
+    Apos = jnp.maximum(A, 0)
+    rawcum = jnp.cumsum(A, axis=1)   # the reference's total_free per prefix
+    poscum = jnp.cumsum(Apos, axis=1)
+    feas_any = rawcum >= W
+    feasible = feas_any.any(axis=1)
+    k_first = jnp.argmax(feas_any, axis=1).astype(jnp.int32)
+    take = jnp.clip(W - (poscum - Apos), 0, Apos)
+    j_last = jnp.argmax(poscum >= W, axis=1).astype(jnp.int32)
+    return feasible, k_first, j_last, take
 
 
-def _get_kernel(N: int, R: int, comm_frac: float):
-    key = (N, R, comm_frac)
+# row-wise first position at which a running count reaches each target
+_searchsorted_rows = jax.vmap(
+    lambda c, t: jnp.searchsorted(c, t, side="left"), in_axes=(0, None))
+
+
+def _spread(elig_units, s_rank, s_node, W, Kj, single, R: int,
+            wmax: int):
+    """Spread slots of one job (lines 25-27), in exact integers.  The
+    pool is the (key, unit) table in the host's stable sort order, and
+    prefix k chooses its first W units that are in ``elig_units`` and of
+    a type ranked below k.  A chosen prefix holds at most ``W <= wmax``
+    units, so their positions are extracted with ``searchsorted`` on the
+    running eligibility count.  Returns per prefix the slot's liveness,
+    the chosen positions ``(R, wmax)`` and which of them exist, the
+    slowest rank used, and the number of distinct servers."""
+    L = elig_units.shape[0]
+    ks = jnp.arange(1, R + 1, dtype=jnp.int32)
+    targets = jnp.arange(1, wmax + 1, dtype=jnp.int32)
+    elig = elig_units[None, :] & (s_rank[None, :] < ks[:, None])
+    csum = jnp.cumsum(elig.astype(jnp.int32), axis=1)
+    n_elig = csum[:, -1]
+    pos = jnp.minimum(_searchsorted_rows(csum, targets),
+                      L - 1).astype(jnp.int32)
+    valid = (targets[None, :] <= W) & (targets[None, :] <= n_elig[:, None])
+    g_rank = jnp.take(s_rank, pos)
+    g_node = jnp.take(s_node, pos)
+    jmax = jnp.max(jnp.where(valid, g_rank, -1), axis=1)
+    # a chosen unit adds a server iff no earlier chosen unit shares it
+    earlier = jnp.arange(wmax)[None, :] < jnp.arange(wmax)[:, None]
+    dup = jnp.any((g_node[:, :, None] == g_node[:, None, :])
+                  & valid[:, None, :] & earlier[None], axis=2)
+    nserv = jnp.sum(valid & jnp.logical_not(dup), axis=1, dtype=jnp.int32)
+    ok = (n_elig >= W) & jnp.logical_not(single) & (ks <= Kj)
+    return ok, pos, valid, jmax, nserv
+
+
+def _spread_width(W: np.ndarray) -> int:
+    """Static width of the compact spread gather: the largest gang,
+    padded to a power of two (min 8) so recompiles stay bounded like
+    :func:`bucket_size`."""
+    return int(max(8, 1 << (int(W.max(initial=1.0)) - 1).bit_length()))
+
+
+def _build_kernel(N: int, R: int, wmax: int):
+    """The fused per-(cluster-geometry) pricing kernel: vmap over the
+    job bucket, jitted once per (B, M, L) shape triple.  Integer inputs
+    and outputs only (see the decision-fidelity note above); the pool's
+    stable argsort arrives pre-computed from the host (NumPy's batched
+    mergesort is the reference operation)."""
+
+    def per_job(avail, node_row, W, Kj, rank, single, s_valid, s_rank,
+                s_node):
+        feasible, k_first, j_last, take = _consolidated(
+            avail, node_row, rank, W, Kj, N, R)
+        ok, pos, _, jmax, nserv = _spread(s_valid, s_rank, s_node, W, Kj,
+                                          single, R, wmax)
+        return feasible, k_first, j_last, take, ok, pos, jmax, nserv
+
+    batched = jax.vmap(per_job, in_axes=(None, None) + (0,) * 7)
+
+    def kernel(avail, node_row, *job_tables):
+        # the cached state views are float64 / int64 host mirrors;
+        # their values are small integers
+        return batched(avail.astype(jnp.int32), node_row.astype(jnp.int32),
+                       *job_tables)
+
+    return jax.jit(kernel)
+
+
+def _get_kernel(N: int, R: int, wmax: int):
+    key = (N, R, wmax)
     if key not in _KERNELS:
         _ob = _obs.get()
         if _ob.enabled:       # process-global cache: 0 in warm processes
             _ob.count("jax_kernel_builds")
-        _KERNELS[key] = _build_kernel(N, R, comm_frac)
+        _KERNELS[key] = _build_kernel(N, R, wmax)
     return _KERNELS[key]
 
 
@@ -423,7 +378,7 @@ class BatchDetails:
     conflict-free wave partitioner: the full candidate-payoff matrix in
     the reference enumeration layout, the winner decode, and the tables
     the payoff-gap bound is computed from.  All job-axis arrays are
-    sliced to the live (unpadded) queue."""
+    sliced to the live (unpadded) queue; every float is host-exact."""
 
     avail0: np.ndarray        # (M,) free units at solve time (copy)
     cumP: np.ndarray          # (M, C+1) Eq. 5 unit-price prefix sums
@@ -431,20 +386,28 @@ class BatchDetails:
     rank: np.ndarray          # (J, M) preference rank of each key's type
     usable: np.ndarray        # (J, M) rank < Kj
     Kj: np.ndarray            # (J,) usable-type count
+    W: np.ndarray             # (J,) gang sizes
     single: np.ndarray        # (J,) single-node flag (no spread slots)
     feasible: np.ndarray      # (J, N) consolidated slot feasible
     k_first: np.ndarray       # (J, N) first feasible preference prefix-1
     packed_payoff: np.ndarray  # (J, N)
     sp_ok: np.ndarray         # (J, R) spread slot live
+    sp_cost: np.ndarray       # (J, R) spread cost, comm penalty included
     sp_pay: np.ndarray        # (J, R)
     sp_jmax: np.ndarray       # (J, R) slowest rank used by spread slot
     sp_nserv: np.ndarray      # (J, R) servers spanned by spread slot
-    sp_counts: np.ndarray     # (J, R, M) spread take per key
+    sp_pos: np.ndarray        # (J, R, wmax) chosen positions in pool order
+    s_m: np.ndarray           # (J, M*C) key of each pool position
     found: np.ndarray         # (J,) a best candidate exists
     win_pay: np.ndarray       # (J,) its payoff
     kb: np.ndarray            # (J,) its preference prefix-1
     slot: np.ndarray          # (J,) node row, or N for the spread slot
     node_row: np.ndarray      # (M,) key -> node row
+
+    def spread_counts(self, r: int, k: int) -> np.ndarray:
+        """(M,) units per key chosen by row ``r``'s spread prefix ``k``."""
+        keys = self.s_m[r, self.sp_pos[r, k - 1, :int(self.W[r])]]
+        return np.bincount(keys, minlength=self.avail0.shape[0])
 
 
 def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
@@ -466,8 +429,6 @@ def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     J = len(jobs)
     if J == 0:
         return ([], None) if details else []
-    if not HAS_JAX:
-        raise RuntimeError("find_alloc_batch requires jax")
 
     gtypes = ps.cluster.gpu_types
     M = len(ps.keys)
@@ -488,38 +449,65 @@ def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     np.cumsum(P, axis=1, out=cumP[:, 1:])
 
     # ---- batched stable sort of the spread pool (host: NumPy's
-    # mergesort is the bitwise reference op and beats XLA's CPU sort) ----
+    # mergesort is the reference op and beats XLA's CPU sort) -----------
     avf = np.asarray(avail, dtype=float)
     unit_ok = np.arange(C)[None, :] < avf[:, None]          # (M, C)
     valid = usable[:, :, None] & unit_ok[None, :, :]        # (B, M, C)
-    ratio_o = np.where(valid, P[None, :, :] / x_key[:, :, None], np.inf)
-    L = M * C
-    ratio_flat = ratio_o.reshape(B, L)
-    order = np.argsort(ratio_flat, axis=-1, kind="stable")
-    s_ratio = np.take_along_axis(ratio_flat, order, axis=-1)
-    s_rank = np.take_along_axis(np.repeat(rank, C, axis=1), order, axis=-1)
-    s_valid = np.take_along_axis(valid.reshape(B, L), order, axis=-1)
+    ratio = np.where(valid, P[None, :, :] / x_key[:, :, None], np.inf)
+    order = np.argsort(ratio.reshape(B, M * C), axis=-1, kind="stable")
+    s_m = order // C                                        # pool key
+    s_rank = np.take_along_axis(rank, s_m, axis=1)
+    s_valid = np.take_along_axis(valid.reshape(B, -1), order, axis=-1)
     s_price = P.reshape(-1)[order]
+    node_row = np.asarray(ps.node_row)
+    wmax = _spread_width(W[:J])
 
-    kern = _get_kernel(N, R, COMM_COST_FRAC)
+    kern = _get_kernel(N, R, wmax)
     _ob = _obs.get()
     if _ob.enabled:
         _ob.count("solver_batch_calls")
         # one XLA compilation per distinct dispatch-shape tuple
-        _ob.kernel_shape((N, R, COMM_COST_FRAC, B, M, C))
+        _ob.kernel_shape((N, R, wmax, B, M, C))
+    i32 = np.int32
     with enable_x64():
         avail_d = avail_dev if avail_dev is not None \
             else jnp.asarray(avf)
-        out = kern(avail_d, jnp.asarray(P), jnp.asarray(cumP),
-                   ps.device_view("node_row"),
-                   jnp.asarray(W), jnp.asarray(Kj), jnp.asarray(rank),
-                   jnp.asarray(u_tab),
-                   jnp.asarray(single), jnp.asarray(s_rank),
-                   jnp.asarray(s_valid), jnp.asarray(s_price),
-                   jnp.asarray(s_ratio), jnp.asarray(order),
-                   jnp.asarray(ratio_o))
-    (feasible, k_first, j_last, take, packed_cost, packed_payoff,
-     sp_ok, sp_pay, sp_jmax, sp_nserv, sp_counts) = map(np.asarray, out)
+        out = kern(avail_d, ps.device_view("node_row"),
+                   jnp.asarray(W.astype(i32)), jnp.asarray(Kj.astype(i32)),
+                   jnp.asarray(rank.astype(i32)), jnp.asarray(single),
+                   jnp.asarray(s_valid), jnp.asarray(s_rank.astype(i32)),
+                   jnp.asarray(node_row[s_m].astype(i32)))
+    (feasible, k_first, j_last, take, sp_ok, sp_pos, sp_jmax,
+     sp_nserv) = (np.asarray(o)[:J] for o in out)
+
+    # ---- costs and payoffs: host-exact, in the reference's order -------
+    # consolidated: per (node, rank) the key's Eq. 5 prefix at its take,
+    # summed over the job's usable ranks like the reference's (N, K) sum
+    Jr = np.arange(J)[:, None]
+    rk, us, u_j = rank[:J], usable[:J], u_tab[:J]
+    t_key = np.where(us, take[Jr, node_row, np.minimum(rk, R - 1)], 0)
+    vs = np.zeros((J, N, R + 1))            # column R: unusable keys
+    vs[Jr, node_row, rk] = np.where(us, cumP[np.arange(M), t_key], 0.0)
+    packed_cost = np.zeros((J, N))
+    for kj in np.unique(Kj[:J]):
+        rows = np.nonzero(Kj[:J] == kj)[0]
+        packed_cost[rows] = np.ascontiguousarray(
+            vs[rows, :, :int(kj)]).sum(axis=-1)
+    packed_payoff = u_j[Jr, j_last] - packed_cost
+    # spread: the chosen units' prices summed in pool order, one gang
+    # size at a time so each row sums exactly like the reference's 1-D
+    # np.sum over W elements, plus the communication penalty
+    sp_cost = np.zeros((J, R))
+    Wj = W[:J].astype(np.intp)
+    for w in np.unique(Wj):
+        rows = np.nonzero(Wj == w)[0]
+        sp_cost[rows] = s_price[rows[:, None, None],
+                                sp_pos[rows, :, :w]].sum(axis=-1)
+    u_jmax = u_j[Jr, np.maximum(sp_jmax, 0)]
+    sp_cost = np.where(sp_nserv > 1,
+                       sp_cost + COMM_COST_FRAC * np.maximum(u_jmax, 0.0)
+                       * (sp_nserv - 1), sp_cost)
+    sp_pay = u_jmax - sp_cost
 
     # ---- winner selection in the reference enumeration order -----------
     # flat candidate axis, per job: for each preference prefix k=1..R,
@@ -529,32 +517,24 @@ def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     pay = np.full((J, R * (N + 1)), -np.inf)
     for k in range(1, R + 1):
         base = (k - 1) * (N + 1)
-        live = feasible[:J] & (k_first[:J] == k - 1)
-        pay[:, base:base + N] = np.where(live, packed_payoff[:J], -np.inf)
-        pay[:, base + N] = np.where(sp_ok[:J, k - 1], sp_pay[:J, k - 1],
+        live = feasible & (k_first == k - 1)
+        pay[:, base:base + N] = np.where(live, packed_payoff, -np.inf)
+        pay[:, base + N] = np.where(sp_ok[:, k - 1], sp_pay[:, k - 1],
                                     -np.inf)
     pay[Kj[:J] == 0] = -np.inf
     win = np.argmax(pay, axis=1)
     win_pay = pay[np.arange(J), win]
 
     # ---- winner materialization -----------------------------------------
-    # Consolidated winners read the kernel's cost/payoff directly: the
-    # unrolled rank-axis accumulation inside the kernel *is* the reference
-    # summation order over bitwise-identical cumP gathers.  Spread winners
-    # (rarer) re-derive their cost on the host in the reference order.
     found = win_pay > -np.inf
     kb, slot = np.divmod(win, N + 1)
-    is_pack = found & (slot < N)
     results: List = [None] * J
     node_ids = [n.node_id for n in ps.cluster.nodes]
 
     if _ob.enabled:
         # runner-up provenance (repro.obs.explain): masked second argmax
         # over the same candidate axis — matches the per-job path's
-        # second-best tracking, including first-maximum tie handling.
-        # Payoffs here come from the batch pay matrix, so they can differ
-        # from the per-job path's by last-ulp amounts (see the decision-
-        # fidelity caveat above) — acceptable for provenance metadata.
+        # second-best tracking, including first-maximum tie handling
         pay2 = pay.copy()
         pay2[np.arange(J), win] = -np.inf
         win2 = np.argmax(pay2, axis=1)
@@ -576,51 +556,27 @@ def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
         def _ru_of(j: int) -> Optional[dict]:
             return None
 
-    pj = np.nonzero(is_pack)[0]
-    if pj.size:
-        hs = slot[pj]
-        jl = j_last[pj, hs]
-        costs = packed_cost[pj, hs]
-        pays = packed_payoff[pj, hs]
-        rates = x_sorted[pj, jl]
-        takes = take[pj, hs].tolist()              # (Jp, R) python floats
-        prefs = pref[pj].tolist()
-        kjs = Kj[pj].tolist()
-        for i, j in enumerate(pj.tolist()):
-            payoff = float(pays[i])
-            if payoff <= 0 and not force:    # mu_j <= 0 (lines 29-33)
-                continue
-            tk = takes[i]
-            nid = node_ids[int(hs[i])]
-            alloc = {(nid, gtypes[prefs[i][kk]]): int(tk[kk])
-                     for kk in range(kjs[i]) if tk[kk] > 0}
-            results[j] = Candidate(alloc, float(costs[i]), payoff,
-                                   float(rates[i]), runner_up=_ru_of(j))
-
-    for j in np.nonzero(found & (slot == N))[0].tolist():
-        k = int(kb[j]) + 1                              # spread prefix k
-        counts = sp_counts[j, k - 1]
-        ms = np.nonzero(counts)[0]
-        unit_m = np.repeat(ms, counts[ms])
-        unit_i = np.concatenate(
-            [np.arange(counts[m]) for m in ms]) if ms.size \
-            else np.zeros(0, dtype=np.intp)
-        prices = P[unit_m, unit_i]
-        # reference summation order == global stable sort restricted
-        # to the chosen units: ratio ascending, flat index tiebreak
-        o = np.lexsort((unit_m * C + unit_i, prices / x_key[j, unit_m]))
-        cost = float(prices[o].sum())
-        jmax = int(sp_jmax[j, k - 1])
-        nserv = int(sp_nserv[j, k - 1])
-        if nserv > 1:
-            cost += COMM_COST_FRAC * max(u_tab[j, jmax], 0.0) * (nserv - 1)
-        payoff = float(u_tab[j, jmax] - cost)
-        if payoff <= 0 and not force:       # mu_j <= 0 (lines 29-33)
+    for j in np.nonzero(found)[0].tolist():
+        h, k = int(slot[j]), int(kb[j]) + 1
+        if h < N:
+            payoff, cost = packed_payoff[j, h], packed_cost[j, h]
+            rate = x_sorted[j, j_last[j, h]]
+        else:
+            payoff, cost = sp_pay[j, k - 1], sp_cost[j, k - 1]
+            rate = x_sorted[j, sp_jmax[j, k - 1]]
+        if payoff <= 0 and not force:        # mu_j <= 0 (lines 29-33)
             continue
-        alloc = {ps.keys[m]: int(counts[m]) for m in ms}
-        results[j] = Candidate(alloc, cost, payoff,
-                               float(x_sorted[j, jmax]),
-                               runner_up=_ru_of(j))
+        if h < N:
+            tk = take[j, h]
+            alloc = {(node_ids[h], gtypes[pref[j, kk]]): int(tk[kk])
+                     for kk in range(int(Kj[j])) if tk[kk] > 0}
+        else:
+            counts = np.bincount(s_m[j, sp_pos[j, k - 1, :Wj[j]]],
+                                 minlength=M)
+            alloc = {ps.keys[m]: int(counts[m])
+                     for m in np.nonzero(counts)[0]}
+        results[j] = Candidate(alloc, float(cost), float(payoff),
+                               float(rate), runner_up=_ru_of(j))
     from repro.analysis import invariants as _inv
     if _inv.sanitize_enabled():
         for job, cand in zip(jobs, results):
@@ -631,14 +587,12 @@ def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
                                      context="(find_alloc_batch)")
     if details:
         det = BatchDetails(
-            avail0=avf.copy(), cumP=cumP, u_tab=u_tab[:J],
-            rank=rank[:J], usable=usable[:J], Kj=Kj[:J],
-            single=single[:J], feasible=feasible[:J],
-            k_first=k_first[:J], packed_payoff=packed_payoff[:J],
-            sp_ok=sp_ok[:J], sp_pay=sp_pay[:J], sp_jmax=sp_jmax[:J],
-            sp_nserv=sp_nserv[:J], sp_counts=sp_counts[:J],
-            found=found, win_pay=win_pay, kb=kb, slot=slot,
-            node_row=np.asarray(ps.node_row))
+            avail0=avf.copy(), cumP=cumP, u_tab=u_j, rank=rk, usable=us,
+            Kj=Kj[:J], W=Wj, single=single[:J], feasible=feasible,
+            k_first=k_first, packed_payoff=packed_payoff, sp_ok=sp_ok,
+            sp_cost=sp_cost, sp_pay=sp_pay, sp_jmax=sp_jmax,
+            sp_nserv=sp_nserv, sp_pos=sp_pos, s_m=s_m[:J], found=found,
+            win_pay=win_pay, kb=kb, slot=slot, node_row=node_row)
         return results, det
     return results
 
@@ -688,11 +642,10 @@ def _spread_bound(det: BatchDetails, r: int, k: int, T: np.ndarray,
     jmax = int(det.sp_jmax[r, k - 1])
     nserv = int(det.sp_nserv[r, k - 1])
     u_jmax = float(det.u_tab[r, jmax])
-    cost_incl = u_jmax - float(det.sp_pay[r, k - 1])
     comm = comm_frac * max(u_jmax, 0.0) * (nserv - 1) if nserv > 1 \
         else 0.0
-    unit_cost = cost_incl - comm
-    counts = det.sp_counts[r, k - 1]
+    unit_cost = float(det.sp_cost[r, k - 1]) - comm
+    counts = det.spread_counts(r, k)
     kept = counts - np.where(T, np.minimum(counts, tv), 0)
     mk = np.nonzero(kept > 0)[0]
     r_keep = int(det.rank[r, mk].max()) if mk.size else 0
@@ -795,162 +748,138 @@ def _wave_accepts(det: BatchDetails, cands: List, rows: List[int],
 # Device-side commit loop: lax.scan over the conflicting remainder
 # --------------------------------------------------------------------------
 
+# Bound on |device payoff - host-exact payoff| relative to the magnitudes
+# it is computed from.  Orders of magnitude above float64 rounding and
+# above the TPU's float32-pair emulation of float64 (about 2^-47).
+_SCAN_TOL = 1e-11
+
+
 def _build_commit_kernel(N: int, R: int, comm_frac: float, wmax: int):
     """One fused ``lax.scan`` running the sequential greedy commit on
     device: each step is a full FIND_ALLOC at the carried state, and the
     winner's take is committed into the ``(free, gamma)`` carry before
     the next step — no host round-trip between conflicting winners.
 
-    Bitwise fidelity mirrors the batch kernel's contract: gamma stays
-    integer on the greedy path, so the step's Eq. 5 prices are *gathers*
-    from the host-exact table ``P_tab[m, u] = umin (umax/umin)^(u/cap)``
-    at index ``gamma + i`` — identical floats to the reference's
-    ``unit_prices(gamma)[m, i]`` at every step.  Packed unit costs
-    accumulate sequentially over the unit index (``np.cumsum`` order)
-    and rank-axis sums are unrolled.
-
+    Feasibility, takes and the spread choice are exact integers, shared
+    with the pricing kernel (:func:`_consolidated`, :func:`_spread`).
     The spread pool needs *no in-scan sort*: the reference's stable
     argsort key is ``(price/throughput, m*c + i)``, each key's ratio
     sequence is non-decreasing in the absolute unit index ``u`` (Eq. 5,
     q >= 1), and the flat-index tie-break across keys depends only on
     the key index (``i < c`` makes ``m`` the dominant digit) — so one
     gamma-independent total order over the whole (key, unit) *table*,
-    computed per job with the host's stable mergesort (the bitwise
-    reference operation), is the pool order at *every* scan step.  A
-    step only applies the current validity window
-    ``gamma_m <= u < gamma_m + free_m`` as a mask in that fixed order.
-    Because a chosen prefix holds at most ``W <= wmax`` units, the step
-    extracts the first-W eligible *positions* with ``searchsorted`` on
-    the running eligibility count and evaluates cost/rank/server count
-    on the compact ``(R, wmax)`` gather — no L-sized scatter or masked
-    reduction per step (those dominated the scan's wall clock).
-    The residual spread-cost ulp caveat of the batch kernel applies
-    unchanged (masked XLA reduction feeding selection only; winner
-    fields are re-derived host-exact after the scan), and additionally
-    the mu_j admission gate compares the *device* payoff against zero,
-    so a job whose reference payoff ties 0.0 to within one ulp could
-    flip — the equivalence suites observe zero such flips.
+    computed per job with the host's stable mergesort, is the pool order
+    at *every* scan step.  A step only applies the current validity
+    window ``gamma_m <= u < gamma_m + free_m`` as a mask in that order.
+
+    Selection needs payoffs, which the device computes in float64 from
+    the host-exact Eq. 5 table ``P_tab[m, u] = umin (umax/umin)^(u/cap)``
+    — approximately: accumulation orders differ from NumPy's, and a TPU
+    emulates float64.  So every step also reports whether its decision
+    is *certain* to be the reference's.  With each payoff known to
+    within ``_SCAN_TOL`` of its magnitude, the contenders are the slots
+    whose interval reaches the best one's.  The step is certain when the
+    mu_j gate clears the same way at both ends of the winner's interval
+    and all contenders provably have bitwise-equal reference payoffs, so
+    that the first contender in enumeration order is the reference's
+    first maximum: consolidated slots whose nodes carry the same
+    (Eq. 5 price row, gamma, take) on every preference rank and the same
+    slowest rank, or a spread slot that takes exactly one such slot's
+    units on a single key (fewer than 8 of them, where ``np.sum`` is the
+    same sequential sum as ``np.cumsum``) — that one is shadowed by the
+    consolidated slot, which comes first.  The host accepts the steps
+    before the first uncertain one (:func:`_scan_commit`).
 
     The init carry buffers are donated (fresh uploads, never reused on
     the host), killing the copy overhead per dispatch."""
 
-    ks = jnp.arange(1, R + 1, dtype=jnp.int32)
-    targets = jnp.arange(1, wmax + 1, dtype=jnp.int32)
-    # row-wise first-position-of-count lookup, bound once per build
-    searchsorted_rows = jax.vmap(
-        lambda c, t: jnp.searchsorted(c, t, side="left"),
-        in_axes=(0, None))
-
-    def scan_fn(free0, gamma0, P_tab, node_row, Wf, Wi, Kj,
-                single, rank, u_tab, s_m, s_u, s_rank, s_price, s_node):
+    def scan_fn(free0, gamma0, P_tab, node_row, prow, Wi, Kj, single,
+                rank, u_tab, s_m, s_u, s_rank, s_price, s_node):
         M, C = P_tab.shape
+        node_row = node_row.astype(jnp.int32)
+        rows = jnp.arange(R)[:, None]
 
         def step(carry, xs):
             free, gamma = carry
-            wf, wi, kj, sing, rk, ut, smj, suj, srkj, sprj, sndj = xs
-            usable = rk < kj
-            av_use = jnp.where(usable, free, 0.0)
-
-            # ---- consolidated slots (batch kernel, single job) -------
-            # (node, rank) cells have at most one contributing key, so
-            # the scatter-add is exact in any accumulation order — and
-            # O(M) per step instead of the batch kernel's dense one-hot
-            # contraction (which would cost N*M per scan step)
-            A = jnp.zeros((N, R + 1), free.dtype).at[
-                node_row, rk].add(av_use)[:, :R]
-            Apos = jnp.maximum(A, 0.0)
-            rc = jnp.zeros((N,), free.dtype)
-            pc = jnp.zeros((N,), free.dtype)
-            raw_cols, pos_cols = [], []
-            for k in range(R):
-                rc = rc + A[:, k]
-                pc = pc + Apos[:, k]
-                raw_cols.append(rc)
-                pos_cols.append(pc)
-            rawcum = jnp.stack(raw_cols, axis=1)
-            poscum = jnp.stack(pos_cols, axis=1)
-            feas_any = rawcum >= wf
-            feasible = feas_any.any(axis=1)
-            k_first = jnp.argmax(feas_any, axis=1)
-            take = jnp.clip(wf - (poscum - Apos), 0.0, Apos)
-            j_last = jnp.argmax(poscum >= wf, axis=1)
+            wi, kj, sing, rk, ut, smj, suj, srkj, sprj, sndj = xs
+            feasible, k_first, j_last, take = _consolidated(
+                free, node_row, rk, wi, kj, N, R)
             take_pad = jnp.concatenate(
-                [take, jnp.zeros((N, 1), free.dtype)], axis=1)
-            t_key = take_pad[node_row, rk].astype(jnp.int32)
+                [take, jnp.zeros((N, 1), jnp.int32)], axis=1)
+            t_key = take_pad[node_row, rk]      # 0 on unusable keys
 
-            # per-key packed cost: sequential unit accumulation over the
-            # P_tab gathers == the reference's cumsum/gather (used price
-            # indices satisfy gamma + i < cap; masked lanes clip + add 0)
+            # ---- consolidated payoffs: sequential unit accumulation
+            # over the P_tab gathers (masked lanes clip and add 0)
             def unit_add(i, acc):
                 col = jnp.minimum(gamma + i, C - 1)
                 p = jnp.take_along_axis(P_tab, col[:, None],
                                         axis=1)[:, 0]
                 return acc + jnp.where(i < t_key, p, 0.0)
             vkey = jax.lax.fori_loop(
-                0, C, unit_add, jnp.zeros((M,), free.dtype))
-            vkey = jnp.where(usable, vkey, 0.0)
-            vs = jnp.zeros((N, R + 1), free.dtype).at[
-                node_row, rk].add(vkey)
-            packed_cost = vs[:, 0]
-            for k in range(1, R):
-                packed_cost = packed_cost + vs[:, k]
-            packed_payoff = ut[j_last] - packed_cost
+                0, C, unit_add, jnp.zeros((M,), P_tab.dtype))
+            packed_cost = jnp.zeros((N,), P_tab.dtype).at[node_row].add(
+                vkey)
+            packed_u = ut[j_last]
+            packed_pay = packed_u - packed_cost
 
-            # ---- spread slots: fixed pool order + validity window ----
-            # the reference's chosen set for prefix k is "first W
-            # eligible units in pool order"; extract exactly those
-            # positions and gather their (key, rank, node, price)
+            # ---- spread payoffs: fixed pool order + validity window ----
             win_lo = jnp.take(gamma, smj)
-            win_free = jnp.take(free, smj)
             in_window = (suj >= win_lo) \
-                & ((suj - win_lo).astype(free.dtype) < win_free)
-            elig = in_window[None, :] & (srkj[None, :] < ks[:, None])
-            csum = jnp.cumsum(elig.astype(jnp.int32), axis=1)
-            n_elig = csum[:, -1]
-            pos = searchsorted_rows(csum, targets)    # (R, wmax)
-            posc = jnp.minimum(pos, csum.shape[1] - 1)
-            # unit j of the prefix exists iff j <= min(W, n_eligible);
-            # gathers past the end are clamped and masked by `valid`
-            valid = (targets[None, :] <= wi) \
-                & (targets[None, :] <= n_elig[:, None])
-            g_m = jnp.take(smj, posc)
-            g_pr = jnp.take(sprj, posc)
-            g_rk = jnp.take(srkj, posc)
-            g_nd = jnp.take(sndj, posc)
-            cost2 = jnp.sum(jnp.where(valid, g_pr, 0.0), axis=1)
-            jmax = jnp.max(jnp.where(valid, g_rk, -1), axis=1)
-            # distinct serving nodes among the chosen units: a unit
-            # counts iff no earlier chosen unit sits on the same node
-            # (exact integer logic on the (R, wmax, wmax) grid)
-            earlier = (jnp.arange(wmax)[None, :]
-                       < jnp.arange(wmax)[:, None])[None]
-            dup = jnp.any((g_nd[:, :, None] == g_nd[:, None, :])
-                          & valid[:, None, :] & earlier, axis=2)
-            sp_nserv = jnp.sum(
-                (valid & jnp.logical_not(dup)).astype(jnp.int32),
-                axis=1)
+                & (suj - win_lo < jnp.take(free, smj))
+            sp_ok, pos, valid, jmax, sp_nserv = _spread(
+                in_window, srkj, sndj, wi, kj, sing, R, wmax)
+            g_m = jnp.take(smj, pos)
+            sp_cost = jnp.sum(jnp.where(valid, jnp.take(sprj, pos), 0.0),
+                              axis=1)
             u_jmax = jnp.take(ut, jnp.maximum(jmax, 0))
-            cost2 = cost2 + jnp.where(
+            sp_cost = sp_cost + jnp.where(
                 sp_nserv > 1,
                 comm_frac * jnp.maximum(u_jmax, 0.0) * (sp_nserv - 1),
                 0.0)
-            sp_ok = (n_elig >= wi) & jnp.logical_not(sing) & (ks <= kj)
-            sp_pay = u_jmax - cost2
+            sp_pay = u_jmax - sp_cost
+            m0 = g_m[:, 0]
+            shadow = jnp.all(jnp.logical_not(valid) | (g_m == m0[:, None]),
+                             axis=1) \
+                & (wi < 8) & jnp.take(feasible, jnp.take(node_row, m0)) \
+                & (jnp.take(t_key, m0) == wi)
 
-            # ---- selection: reference enumeration order, first max ---
-            live = feasible[None, :] \
-                & (k_first[None, :] == jnp.arange(R)[:, None])
-            payM = jnp.where(live, packed_payoff[None, :], -jnp.inf)
-            spread_col = jnp.where(sp_ok, sp_pay, -jnp.inf)[:, None]
-            pay = jnp.concatenate([payM, spread_col], axis=1).reshape(-1)
+            # ---- selection: reference enumeration order ---------------
+            live = feasible[None, :] & (k_first[None, :] == rows)
+            pay = jnp.concatenate(
+                [jnp.where(live, packed_pay[None, :], -jnp.inf),
+                 jnp.where(sp_ok & jnp.logical_not(shadow), sp_pay,
+                           -jnp.inf)[:, None]], axis=1).reshape(-1)
             pay = jnp.where(kj > 0, pay, -jnp.inf)
-            win = jnp.argmax(pay)
-            win_pay = pay[win]
-            won = win_pay > 0.0               # mu_j gate (device float)
+            err = _SCAN_TOL * jnp.concatenate(
+                [jnp.broadcast_to(jnp.abs(packed_u) + packed_cost, (R, N)),
+                 (jnp.abs(u_jmax) + sp_cost)[:, None]], axis=1).reshape(-1)
+            is_live = pay > -jnp.inf
+            best = jnp.argmax(pay)
+            contender = is_live & (pay + err >= pay[best] - err[best])
+            win = jnp.argmax(contender).astype(jnp.int32)
             slot = win % (N + 1)
+            k_sel = win // (N + 1)
+            # consolidated slots with the winner's (Eq. 5 price row,
+            # gamma, take) on every rank and its slowest rank
+            sig = jnp.full((N, R + 1, 3), -1, jnp.int32).at[
+                node_row, rk].set(jnp.stack([prow, gamma, t_key], 1))[:, :R]
+            h_win = jnp.minimum(slot, N - 1)
+            same = jnp.all(sig == sig[h_win], axis=(1, 2)) \
+                & (j_last == j_last[h_win]) & (slot < N)
+            # spread slots choosing the winner's very units
+            chosen = jnp.where(valid, pos, -1)
+            same_sp = jnp.all(chosen == chosen[k_sel], axis=1) & (slot == N)
+            in_class = jnp.concatenate(
+                [jnp.broadcast_to(same, (R, N)), same_sp[:, None]],
+                axis=1).reshape(-1)
+            won = pay[win] > 0.0                   # mu_j gate
+            sure = jnp.logical_not(is_live.any()) | (
+                jnp.all(in_class | jnp.logical_not(contender))
+                & ((pay[win] - err[win] > 0.0)
+                   | (jnp.max(pay + err) <= 0.0)))
+
             # spread counts only materialize for the winning prefix:
             # one wmax-sized integer scatter (duplicate keys add)
-            k_sel = win // (N + 1)
             sp_cnt_win = jnp.zeros((M,), jnp.int32).at[g_m[k_sel]].add(
                 valid[k_sel].astype(jnp.int32))
             counts = jnp.where(
@@ -961,13 +890,12 @@ def _build_commit_kernel(N: int, R: int, comm_frac: float, wmax: int):
                 jnp.zeros((M,), jnp.int32))
             pay2 = pay.at[win].set(-jnp.inf)
             win2 = jnp.argmax(pay2)
-            outs = (won, win.astype(jnp.int32), counts,
-                    win2.astype(jnp.int32), pay2[win2], sp_nserv)
-            return ((free - counts.astype(free.dtype), gamma + counts),
-                    outs)
+            outs = (won, win, counts, win2.astype(jnp.int32), pay2[win2],
+                    sp_nserv, sure)
+            return (free - counts, gamma + counts), outs
 
         (free_f, gamma_f), ys = jax.lax.scan(
-            step, (free0, gamma0), (Wf, Wi, Kj, single, rank, u_tab,
+            step, (free0, gamma0), (Wi, Kj, single, rank, u_tab,
                                     s_m, s_u, s_rank, s_price, s_node))
         return (free_f, gamma_f) + ys
 
@@ -988,16 +916,45 @@ def _get_commit_kernel(N: int, R: int, comm_frac: float, wmax: int):
 def _scan_commit(jobs: List, avail: np.ndarray, gamma: np.ndarray,
                  ps, now: float, utility) -> Dict:
     """Run the sequential greedy commit over ``jobs`` (already in commit
-    order) in one device scan; mutates ``avail``/``gamma`` in place and
-    returns ``{job_id: Candidate}`` for the winners.  Winner cost/
-    payoff/rate are re-derived host-exact from the per-step counts and
-    the accumulated gamma, exactly like the batch kernel's winner
-    materialization."""
+    order) through the device scan; mutates ``avail``/``gamma`` in place
+    and returns ``{job_id: Candidate}`` for the winners.  A step the
+    device cannot certify (see :func:`_build_commit_kernel`) ends the
+    accepted prefix: the host re-solves that job with the reference
+    FIND_ALLOC at the accumulated state, and the scan resumes after it."""
+    from repro.core.dp import _find_alloc_arrays
+
+    _ob = _obs.get()
+    results: Dict = {}
+    start = 0
+    while start < len(jobs):
+        start += _scan_prefix(jobs[start:], avail, gamma, ps, now,
+                              utility, results)
+        if start == len(jobs):
+            break
+        if _ob.enabled:
+            _ob.count("solver.scan_host_steps")
+        job = jobs[start]
+        cand = _find_alloc_arrays(job, avail, gamma, ps, now, utility,
+                                  force=False)
+        if cand:
+            results[job.job_id] = cand
+            for key, v in cand.alloc.items():
+                m = ps.key_index[key]
+                avail[m] -= v
+                gamma[m] += v
+        start += 1
+    return results
+
+
+def _scan_prefix(jobs: List, avail: np.ndarray, gamma: np.ndarray,
+                 ps, now: float, utility, results: Dict) -> int:
+    """One scan dispatch over ``jobs``: commits the steps before the
+    first uncertain one into ``avail``/``gamma``/``results`` and returns
+    how many that was.  Winner cost/payoff/rate are re-derived
+    host-exact from the per-step counts and the accumulated gamma."""
     from repro.core.dp import COMM_COST_FRAC, Candidate
 
     J = len(jobs)
-    if J == 0:
-        return {}
     M = len(ps.keys)
     N = ps.n_node_rows
     R = len(ps.cluster.gpu_types)
@@ -1012,13 +969,15 @@ def _scan_commit(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     jt = _job_tables(jobs, ps, now, utility, B)
     # Eq. 5 gather table: gamma is integer-valued on the greedy path and
     # every *used* unit index satisfies gamma + i < cap, so P_tab rows
-    # are bitwise the reference's unit_prices(gamma) at every scan step
+    # are bitwise the reference's unit_prices(gamma) at every scan step;
+    # keys with equal rows price identically at equal gamma
     P_tab = ps.unit_prices(np.zeros(M), C)
+    prow = np.unique(P_tab, axis=0, return_inverse=True)[1].reshape(-1)
     node_row = np.asarray(ps.node_row)
 
     # fixed per-job spread-pool order over the whole (key, unit) table
     # (gamma-independent — see the kernel docstring): NumPy's stable
-    # mergesort is the bitwise reference sort, computed once per scan
+    # mergesort is the reference sort, computed once per scan
     L = M * C
     ratio_tab = np.where(jt.usable[:, :, None],
                          P_tab[None, :, :] / jt.x_key[:, :, None],
@@ -1030,10 +989,7 @@ def _scan_commit(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     s_price = P_tab.reshape(-1)[order]
     s_node = node_row[s_m].astype(np.int32)
 
-    # static prefix width for the compact spread gather, padded to a
-    # power of two (min 8) so recompiles stay bounded like bucket_size
-    wmax = int(max(8, 1 << (int(jt.W[:J].max(initial=1.0))
-                            - 1).bit_length()))
+    wmax = _spread_width(jt.W[:J])
     kern = _get_commit_kernel(N, R, COMM_COST_FRAC, wmax)
     _ob = _obs.get()
     if _ob.enabled:
@@ -1042,28 +998,28 @@ def _scan_commit(jobs: List, avail: np.ndarray, gamma: np.ndarray,
         # one XLA compile per distinct (geometry, carry/xs shape) tuple
         _ob.kernel_shape(("commit_scan", N, R, COMM_COST_FRAC, B, M, C,
                           wmax))
+    i32 = np.int32
     with enable_x64():
         # fresh uploads: the kernel donates these carry buffers
-        free0 = jnp.asarray(np.asarray(avail, dtype=float))
-        gamma0 = jnp.asarray(np.asarray(gamma, dtype=np.int32))
+        free0 = jnp.asarray(np.asarray(avail).astype(i32))
+        gamma0 = jnp.asarray(np.asarray(gamma).astype(i32))
         out = kern(free0, gamma0, jnp.asarray(P_tab),
-                   ps.device_view("node_row"),
-                   jnp.asarray(jt.W),
-                   jnp.asarray(jt.W.astype(np.int32)),
-                   jnp.asarray(jt.Kj.astype(np.int32)),
+                   ps.device_view("node_row"), jnp.asarray(prow.astype(i32)),
+                   jnp.asarray(jt.W.astype(i32)),
+                   jnp.asarray(jt.Kj.astype(i32)),
                    jnp.asarray(jt.single),
-                   jnp.asarray(jt.rank.astype(np.int32)),
+                   jnp.asarray(jt.rank.astype(i32)),
                    jnp.asarray(jt.u_tab), jnp.asarray(s_m),
                    jnp.asarray(s_u), jnp.asarray(s_rank),
                    jnp.asarray(s_price), jnp.asarray(s_node))
-    (free_f, gamma_f, won, win, counts, win2, win2_pay,
-     sp_nserv) = map(np.asarray, out)
+    (free_f, gamma_f, won, win, counts, win2, win2_pay, sp_nserv,
+     sure) = map(np.asarray, out)
+    n_ok = int(np.argmin(sure[:J])) if not sure[:J].all() else J
 
     node_ids = [n.node_id for n in ps.cluster.nodes]
-    results: Dict = {}
     gam_run = np.asarray(gamma, dtype=np.int64).copy()
     want_ru = _ob.enabled
-    for p in range(J):
+    for p in range(n_ok):
         if not won[p]:
             continue
         cnts = counts[p]
@@ -1111,26 +1067,26 @@ def _scan_commit(jobs: List, avail: np.ndarray, gamma: np.ndarray,
                                             runner_up=ru)
         gam_run[ms] += cnts[ms]
 
-    total = counts[:J].sum(axis=0)
+    total = counts[:n_ok].sum(axis=0)
     avail -= total
     gamma += total
     from repro.analysis import invariants as _inv
     if _inv.sanitize_enabled():
         # the donated-carry outputs must agree with the host accounting
-        # (all quantities are integer-valued, so this is exact)
-        if not np.array_equal(free_f.astype(float),
-                              np.asarray(avail, dtype=float)):
+        # when every step was accepted (all quantities are integers)
+        if n_ok == J and not np.array_equal(
+                free_f.astype(float), np.asarray(avail, dtype=float)):
             _inv.violate("conservation",
                          "scan carry free_arr diverged from host delta",
                          max_err=float(np.abs(free_f
                                               - np.asarray(avail)).max()))
-        for job in jobs:
+        for job in jobs[:n_ok]:
             cand = results.get(job.job_id)
             if cand is not None:
                 _inv.check_candidate(job.job_id, job.n_workers,
                                      cand.alloc, cand.payoff, cand.cost,
                                      context="(scan_commit)")
-    return results
+    return n_ok
 
 
 def commit_greedy(queue: List, avail: np.ndarray, gamma: np.ndarray,

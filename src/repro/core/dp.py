@@ -31,9 +31,9 @@ exact DP's empty-branch candidate scan, and routes the greedy *commit*
 loop through the conflict-free wave partitioner + device-side
 ``lax.scan`` (``batch_solver.commit_greedy``); ``"numpy"`` keeps the
 per-job path — the sequential loop below is the bitwise equivalence
-oracle for the device commit; ``"auto"``/None auto-detects (jax when
-importable and the queue clears the calibrated crossover).  Both
-backends produce bit-identical decisions.
+oracle for the device commit; ``"auto"``/None picks jax when the queue
+clears the calibrated crossover.  Both backends produce bit-identical
+decisions.
 
 ``free=None`` prices against the PriceState's persistent ``free_arr``
 (maintained incrementally by ``commit()``/``release()``) instead of

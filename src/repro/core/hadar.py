@@ -41,7 +41,7 @@ class HadarScheduler(Scheduler):
         # which the paper's own Fig. 1 never exhibits — are eliminated.
         self.work_conserving = work_conserving
         # pricing backend for the queue-wide candidate scans:
-        # "jax" (batched device kernel) | "numpy" | "auto" (detect).
+        # "jax" (batched device kernel) | "numpy" | "auto" (by size).
         # Decisions are bit-identical across backends.
         self.solver = solver
         self._had_completion = True     # force full pass on round 0

@@ -260,6 +260,8 @@ def main(argv=None) -> int:
     ap.add_argument("--json", type=str, default=None, metavar="OUT",
                     help="also write the table as JSON")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.core.trace import philly_trace, simulation_cluster
     cluster = simulation_cluster()

@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels for the job-side models, with pure-jnp oracles in
+:mod:`repro.kernels.ref` and jit'd model-layout wrappers in
+:mod:`repro.kernels.ops`."""
+import jax
+
+
+def interpret_default() -> bool:
+    """Pallas interpret mode for the backend this process runs on, asked
+    at call time: the kernels compile through Mosaic on a TPU and run in
+    the Pallas interpreter everywhere else."""
+    return jax.default_backend() != "tpu"

@@ -11,11 +11,14 @@ Blockwise online-softmax with explicit BlockSpec VMEM tiling:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_default
 
 NEG_INF = -1e30
 
@@ -64,8 +67,11 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
-    """q: (B,Hq,S,D); k,v: (B,Hkv,S,D) -> (B,Hq,S,D)."""
+                    interpret: Optional[bool] = None):
+    """q: (B,Hq,S,D); k,v: (B,Hkv,S,D) -> (B,Hq,S,D).  ``interpret=None``
+    picks the mode from the backend (:func:`interpret_default`)."""
+    if interpret is None:
+        interpret = interpret_default()
     B, Hq, S, D = q.shape
     Hkv = k.shape[1]
     G = Hq // Hkv

@@ -2,7 +2,8 @@
 
 Model code calls these with model-layout tensors; the wrappers transpose
 to kernel layout, pad to tile multiples, and dispatch to the Pallas
-implementation (interpret=True on CPU — the TPU build flips the flag).
+implementation, which picks Pallas interpret mode from the backend at
+call time (compiled on a TPU, interpreted elsewhere).
 ``impl="xla"`` falls through to the jnp oracle (the default inside models,
 since XLA fuses those fine and the dry-run needs no Pallas lowering).
 """
@@ -17,8 +18,6 @@ from repro.kernels import ref
 from repro.kernels import flash_attention as _fa
 from repro.kernels import rwkv6_scan as _rwkv
 from repro.kernels import rmsnorm as _rms
-
-INTERPRET = True  # CPU container; TPU deployments set False
 
 
 def flash_attention(q, k, v, causal: bool = True, window: int = 0,
@@ -40,8 +39,7 @@ def flash_attention(q, k, v, causal: bool = True, window: int = 0,
             kt = jnp.concatenate([kt, zk], axis=2)
             vt = jnp.concatenate([vt, zk], axis=2)
         out = _fa.flash_attention(qt, kt, vt, causal=causal, window=window,
-                                  block_q=bq, block_k=bk,
-                                  interpret=INTERPRET)
+                                  block_q=bq, block_k=bk)
         if pad:
             out = out[:, :, :S]
     return jnp.swapaxes(out, 1, 2)
@@ -62,8 +60,7 @@ def rwkv6_scan(r, k, v, w, u, state, impl: str = "pallas", chunk: int = 32):
                 return jnp.concatenate([t, z], axis=2)
             rt, kt, vt = zpad(rt), zpad(kt), zpad(vt)
             wt = zpad(wt, 1.0)   # decay 1 = no-op steps
-        out, s = _rwkv.rwkv6_scan(rt, kt, vt, wt, u, state, chunk=chunk,
-                                  interpret=INTERPRET)
+        out, s = _rwkv.rwkv6_scan(rt, kt, vt, wt, u, state, chunk=chunk)
         if pad:
             out = out[:, :, :S]
     return jnp.swapaxes(out, 1, 2), s
@@ -72,4 +69,4 @@ def rwkv6_scan(r, k, v, w, u, state, impl: str = "pallas", chunk: int = 32):
 def rmsnorm(x, scale, eps: float = 1e-5, impl: str = "pallas"):
     if impl == "xla":
         return ref.rmsnorm_ref(x, scale, eps)
-    return _rms.rmsnorm(x, scale, eps, interpret=INTERPRET)
+    return _rms.rmsnorm(x, scale, eps)

@@ -2,10 +2,13 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import interpret_default
 
 
 def _kernel(x_ref, s_ref, o_ref, *, eps: float):
@@ -16,8 +19,11 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
 
 
 def rmsnorm(x, scale, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool = True):
-    """x: (..., D) -> same shape; scale: (D,)."""
+            interpret: Optional[bool] = None):
+    """x: (..., D) -> same shape; scale: (D,).  ``interpret=None`` picks
+    the mode from the backend (:func:`interpret_default`)."""
+    if interpret is None:
+        interpret = interpret_default()
     orig_shape = x.shape
     D = x.shape[-1]
     xf = x.reshape(-1, D)

@@ -5,6 +5,8 @@ import sys
 os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the repo root: benchmarks/ and chip_smoke.py
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 import numpy as np
 import pytest

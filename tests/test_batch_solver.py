@@ -19,9 +19,8 @@ except ModuleNotFoundError:  # offline CI image — vendored fallback
     from _hypothesis_compat import given, settings, strategies as st
 
 import _seed_reference as ref
-from repro.core.batch_solver import (HAS_JAX, bucket_size,
-                                     resolve_solver, solver_threshold,
-                                     use_batch)
+from repro.core.batch_solver import (bucket_size, resolve_solver,
+                                     solver_threshold, use_batch)
 from repro.core.dp import _find_alloc_arrays, dp_allocation, find_alloc
 from repro.core.hadar import HadarScheduler
 from repro.core.pricing import PriceState
@@ -31,9 +30,6 @@ from repro.core.trace import testbed_cluster as _testbed_cluster
 from repro.core.types import Cluster, Job, Node
 from repro.core.utility import effective_throughput, weighted_inverse
 from repro.sim.engine import simulate_events, simulate_rounds
-
-needs_jax = pytest.mark.skipif(not HAS_JAX, reason="jax unavailable")
-
 
 def _same_candidate(a, b):
     if (a is None) != (b is None):
@@ -69,17 +65,16 @@ def _jobs_with_edges(cluster, seed, n):
 
 def test_resolve_and_dispatch_rules():
     assert resolve_solver("numpy") == "numpy"
-    assert resolve_solver(None) in ("jax", "numpy")
+    assert resolve_solver(None) == "jax"
     with pytest.raises(ValueError):
         resolve_solver("tpu")
     assert not use_batch("numpy", 10_000)
-    if HAS_JAX:
-        assert resolve_solver("auto") == "jax"
-        assert use_batch("jax", 1)
-        # the auto crossover comes from the calibration JSON (env var
-        # overrides notwithstanding), not a hard-coded constant
-        assert not use_batch("auto", solver_threshold() - 1)
-        assert use_batch("auto", solver_threshold())
+    assert resolve_solver("auto") == "jax"
+    assert use_batch("jax", 1)
+    # the auto crossover comes from the calibration JSON (env var
+    # overrides notwithstanding), not a hard-coded constant
+    assert not use_batch("auto", solver_threshold() - 1)
+    assert use_batch("auto", solver_threshold())
 
 
 def test_bucket_size_powers_of_two():
@@ -91,7 +86,6 @@ def test_bucket_size_powers_of_two():
 # FIND_ALLOC equivalence: batched kernel vs per-job NumPy path
 # ---------------------------------------------------------------------------
 
-@needs_jax
 def test_batch_empty_queue():
     from repro.core.batch_solver import find_alloc_batch
     cluster = _mixed_cluster()
@@ -100,7 +94,6 @@ def test_batch_empty_queue():
                             ps, 0.0, effective_throughput) == []
 
 
-@needs_jax
 @pytest.mark.parametrize("n", [1, 7, 19])   # below / at / across bucket 8|32
 def test_batch_matches_perjob_padding_and_edges(n):
     """Bit-identical candidates across bucket-padding boundaries, with
@@ -128,7 +121,6 @@ def test_batch_matches_perjob_padding_and_edges(n):
             assert _same_candidate(a, b), (job.job_id, force, a, b)
 
 
-@needs_jax
 def test_batch_job_with_no_usable_types_is_none():
     from repro.core.batch_solver import find_alloc_batch
     cluster = _mixed_cluster()
@@ -145,7 +137,6 @@ def test_batch_job_with_no_usable_types_is_none():
         assert _same_candidate(a, out[ji])
 
 
-@needs_jax
 def test_batch_single_node_copies_never_spread():
     """HadarE fork copies (single_node=True) must only receive
     consolidated candidates — identical to the per-job path."""
@@ -165,7 +156,6 @@ def test_batch_single_node_copies_never_spread():
             assert len({h for (h, _) in b.alloc}) == 1
 
 
-@needs_jax
 def test_batch_custom_utility_fallback_path():
     """Non-default utilities take the scalar u-table path; results still
     match the per-job kernel exactly."""
@@ -187,7 +177,6 @@ def test_batch_custom_utility_fallback_path():
 # DP / scheduler / engine equivalence across backends
 # ---------------------------------------------------------------------------
 
-@needs_jax
 @pytest.mark.parametrize("seed,n,max_exact", [(0, 40, 24), (7, 8, 24),
                                               (3, 20, 24)])
 def test_dp_allocation_solver_backends_identical(seed, n, max_exact):
@@ -211,7 +200,6 @@ def test_dp_allocation_solver_backends_identical(seed, n, max_exact):
         assert s_np[jid].payoff == s_jx[jid].payoff
 
 
-@needs_jax
 @pytest.mark.parametrize("seed,n,now", [(1, 24, 0.0), (5, 80, 0.0),
                                         (2, 40, 7200.0)])
 def test_hadar_round_jax_matches_seed_reference(seed, n, now):
@@ -224,7 +212,6 @@ def test_hadar_round_jax_matches_seed_reference(seed, n, now):
     assert out_ref == out_jax
 
 
-@needs_jax
 def test_hadar_round_jax_multipod_bursty():
     pods = multi_cluster(n_pods=3, nodes_per_pod=5, gpus_per_node=4,
                          pod_types=["v100", "p100", "k80"],
@@ -237,7 +224,6 @@ def test_hadar_round_jax_multipod_bursty():
                                                      pods))
 
 
-@needs_jax
 @pytest.mark.parametrize("engine", [simulate_rounds, simulate_events])
 def test_engines_solver_backends_identical(engine):
     """Whole simulations agree across backends: finish times, restarts,
@@ -255,7 +241,6 @@ def test_engines_solver_backends_identical(engine):
     assert abs(r_np.avg_gru() - r_jx.avg_gru()) == 0.0
 
 
-@needs_jax
 def test_hadare_solver_backends_identical():
     """The vectorized HadarE backend (single_node copies through the
     batched kernel) is backend-independent end to end."""
@@ -393,7 +378,6 @@ def test_scheduler_rebuilds_pricestate_on_inplace_mutation():
 # device-buffer cache invalidation (property test)
 # ---------------------------------------------------------------------------
 
-@needs_jax
 @settings(max_examples=20, deadline=None)
 @given(seed=st.integers(0, 10_000))
 def test_gamma_mutations_always_invalidate_device_views(seed):
@@ -434,7 +418,6 @@ def test_gamma_mutations_always_invalidate_device_views(seed):
         assert "gamma" not in ps._dirty      # view freshly re-uploaded
 
 
-@needs_jax
 def test_device_view_caches_until_dirty():
     cluster = _mixed_cluster()
     ps = PriceState(cluster, _jobs_with_edges(cluster, seed=2, n=2),

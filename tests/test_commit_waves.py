@@ -17,8 +17,8 @@ import pytest
 from _hypothesis_compat import given, settings
 from _hypothesis_compat import strategies as st
 from repro import obs
-from repro.core.batch_solver import (ENV_THRESHOLD, HAS_JAX,
-                                     _wave_accepts, commit_threshold,
+from repro.core.batch_solver import (ENV_THRESHOLD, _wave_accepts,
+                                     commit_threshold,
                                      find_alloc_batch, load_calibration,
                                      resolve_backend, solver_threshold,
                                      use_commit)
@@ -26,8 +26,6 @@ from repro.core.dp import Candidate, dp_allocation
 from repro.core.pricing import PriceState
 from repro.core.types import Cluster, Job, Node
 from repro.core.utility import effective_throughput
-
-needs_jax = pytest.mark.skipif(not HAS_JAX, reason="jax not importable")
 
 HORIZON = 7 * 24 * 3600.0
 TYPES = ["v100", "p100", "k80", "t4"]
@@ -82,7 +80,6 @@ def _assert_identical(a, b):
 # property: wave + scan commits == sequential oracle
 # ---------------------------------------------------------------------------
 
-@needs_jax
 @settings(max_examples=10)
 @given(seed=st.integers(0, 9_999), n=st.integers(6, 40))
 def test_commit_matches_oracle_random_geometry(seed, n):
@@ -93,7 +90,6 @@ def test_commit_matches_oracle_random_geometry(seed, n):
     _assert_identical(ref, dev)
 
 
-@needs_jax
 @settings(max_examples=6)
 @given(seed=st.integers(0, 9_999))
 def test_commit_forced_key_conflicts(seed):
@@ -109,7 +105,29 @@ def test_commit_forced_key_conflicts(seed):
     _assert_identical(ref, dev)
 
 
-@needs_jax
+@pytest.mark.parametrize("tol", [1e-3, 10.0])
+def test_uncertain_scan_steps_go_to_the_host_oracle(monkeypatch, tol):
+    """With the scan's float-error bound inflated, it certifies fewer
+    steps (at 10x a payoff's magnitude, none with a live candidate):
+    the host re-solves each with the reference FIND_ALLOC and the scan
+    resumes after it, so decisions stay bitwise the oracle's."""
+    from repro.core import batch_solver as bs
+    monkeypatch.setattr(bs, "_SCAN_TOL", tol)
+    monkeypatch.setattr(bs, "_COMMIT_KERNELS", {})
+    rng = np.random.RandomState(3)
+    cluster = Cluster([Node(0, {"v100": 4}), Node(1, {"v100": 2, "k80": 4})])
+    jobs = [Job(j, 0.0, int(rng.randint(1, 4)), int(rng.randint(1, 50)),
+                10, {"v100": float(rng.uniform(0.5, 3.0)),
+                     "k80": float(rng.uniform(0.1, 1.0))})
+            for j in range(14)]
+    with obs.session(trace=False, decisions=False) as ob:
+        ref, dev = _run_both(cluster, jobs)
+    _assert_identical(ref, dev)
+    counters = ob.metrics.summary()["counters"]
+    assert counters.get("solver_scan_calls", 0) >= 2
+    assert counters.get("solver.scan_host_steps", 0) >= 1
+
+
 @settings(max_examples=6)
 @given(seed=st.integers(0, 9_999))
 def test_commit_gangs_span_sibling_nodes(seed):
@@ -126,7 +144,6 @@ def test_commit_gangs_span_sibling_nodes(seed):
     _assert_identical(ref, dev)
 
 
-@needs_jax
 def test_commit_payoff_tie_rejects_prefix():
     """Two bitwise-identical jobs contending for one winner slot: the
     runner-up ties the winner's payoff, so the wave-safety test must
@@ -147,7 +164,6 @@ def test_commit_payoff_tie_rejects_prefix():
     assert tv.sum() == sum(cands[0].alloc.values())
 
 
-@needs_jax
 def test_wave_accepts_disjoint_winners_in_one_wave():
     """Jobs usable only on pairwise-disjoint keys commit as one wave."""
     cluster = Cluster([Node(i, {TYPES[i]: 4}) for i in range(3)])
@@ -171,7 +187,6 @@ def test_wave_accepts_disjoint_winners_in_one_wave():
     assert len(dev) == 3
 
 
-@needs_jax
 def test_commit_path_reports_waves_through_obs():
     cluster = Cluster([Node(i, {TYPES[i % 3]: 4}) for i in range(6)])
     rng = np.random.RandomState(11)
@@ -266,19 +281,18 @@ def test_env_threshold_override(monkeypatch):
 def test_use_commit_dispatch_rules(monkeypatch):
     monkeypatch.delenv(ENV_THRESHOLD, raising=False)
     assert not use_commit("numpy", 10_000)
-    if HAS_JAX:                  # "jax" raises without the backend
-        assert not use_commit("jax", 0)
-        assert use_commit("jax", 1)
-        thr = commit_threshold()
-        assert not use_commit("auto", thr - 1)
-        assert use_commit("auto", thr)
+    assert not use_commit("jax", 0)
+    assert use_commit("jax", 1)
+    thr = commit_threshold()
+    assert not use_commit("auto", thr - 1)
+    assert use_commit("auto", thr)
 
 
 def test_resolve_backend_logs_crossover(monkeypatch):
     monkeypatch.delenv(ENV_THRESHOLD, raising=False)
     with obs.session(trace=False, decisions=False) as ob:
         backend = resolve_backend("auto", 10_000)
-    assert backend == ("jax" if HAS_JAX else "numpy")
+    assert backend == "jax"
     summ = ob.metrics.summary()
     assert summ["gauges"].get("solver.auto_min_jobs") \
         == solver_threshold()

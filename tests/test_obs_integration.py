@@ -161,9 +161,6 @@ def test_invariant_check_counters_tick_under_sanitize():
 
 
 def test_jax_recompile_counter_on_batched_path():
-    from repro.core.batch_solver import HAS_JAX
-    if not HAS_JAX:
-        pytest.skip("jax unavailable")
     cluster = simulation_cluster()
     with obs.session(trace=False, decisions=False) as ob:
         simulate_events(HadarScheduler(solver="jax"), _jobs(), cluster,
