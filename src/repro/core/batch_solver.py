@@ -156,23 +156,21 @@ def resolve_solver(solver: Optional[str]) -> str:
 def resolve_backend(solver: Optional[str], n_jobs: int) -> str:
     """The backend a queue of ``n_jobs`` actually runs on: applies the
     calibrated ``auto`` crossover (see :func:`solver_threshold`) on top
-    of :func:`resolve_solver`, and logs the chosen crossover through
-    ``repro.obs`` so traces show which side of the threshold a consult
-    landed on."""
+    of :func:`resolve_solver`.  Traces show the side of the crossover
+    a dispatch landed on in the ``solver_dispatch`` span's args."""
     mode = check_solver(solver)
     if mode == "auto":
-        thr = solver_threshold()
-        backend = "jax" if n_jobs >= thr else "numpy"
-    else:
-        thr = None
-        backend = resolve_solver(mode)
-    _ob = _obs.get()
-    if _ob.enabled:
-        if thr is not None:
-            _ob.gauge("solver.auto_min_jobs", thr)
-        _ob.instant("solver.resolve", backend=backend, n_jobs=n_jobs,
-                    threshold=thr)
-    return backend
+        return "jax" if n_jobs >= solver_threshold() else "numpy"
+    return resolve_solver(mode)
+
+
+def crossover(solver: Optional[str], commit: bool = False) -> Optional[int]:
+    """The queue-size crossover ``solver`` applies — the pricing one, or
+    with ``commit`` the greedy-commit one — or None where the flag
+    forces a backend (the ``threshold`` arg of ``solver_dispatch``)."""
+    if check_solver(solver) != "auto":
+        return None
+    return commit_threshold() if commit else solver_threshold()
 
 
 def use_batch(solver: Optional[str], n_jobs: int) -> bool:
@@ -436,165 +434,169 @@ def find_alloc_batch(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     R = len(gtypes)
     C = int(max(ps.cap_arr.max(initial=1.0), avail.max(initial=1.0), 1.0))
 
-    # ---- per-job gather tables (host; identical scalar math) -----------
-    B = bucket_size(J)
-    jt = _job_tables(jobs, ps, now, utility, B)
-    W, single, Kj, pref = jt.W, jt.single, jt.Kj, jt.pref
-    x_sorted, u_tab = jt.x_sorted, jt.u_tab
-    rank, usable, x_key = jt.rank, jt.usable, jt.x_key
-
-    # ---- shared price tables (host NumPy: bitwise Eq. 5 prefixes) ------
-    P = ps.unit_prices(np.asarray(gamma, dtype=float), C)
-    cumP = np.zeros((M, C + 1))
-    np.cumsum(P, axis=1, out=cumP[:, 1:])
-
-    # ---- batched stable sort of the spread pool (host: NumPy's
-    # mergesort is the reference op and beats XLA's CPU sort) -----------
-    avf = np.asarray(avail, dtype=float)
-    unit_ok = np.arange(C)[None, :] < avf[:, None]          # (M, C)
-    valid = usable[:, :, None] & unit_ok[None, :, :]        # (B, M, C)
-    ratio = np.where(valid, P[None, :, :] / x_key[:, :, None], np.inf)
-    order = np.argsort(ratio.reshape(B, M * C), axis=-1, kind="stable")
-    s_m = order // C                                        # pool key
-    s_rank = np.take_along_axis(rank, s_m, axis=1)
-    s_valid = np.take_along_axis(valid.reshape(B, -1), order, axis=-1)
-    s_price = P.reshape(-1)[order]
-    node_row = np.asarray(ps.node_row)
-    wmax = _spread_width(W[:J])
-
-    kern = _get_kernel(N, R, wmax)
     _ob = _obs.get()
-    if _ob.enabled:
-        _ob.count("solver_batch_calls")
-        # one XLA compilation per distinct dispatch-shape tuple
-        _ob.kernel_shape((N, R, wmax, B, M, C))
-    i32 = np.int32
-    with enable_x64():
-        avail_d = avail_dev if avail_dev is not None \
-            else jnp.asarray(avf)
-        out = kern(avail_d, ps.device_view("node_row"),
-                   jnp.asarray(W.astype(i32)), jnp.asarray(Kj.astype(i32)),
-                   jnp.asarray(rank.astype(i32)), jnp.asarray(single),
-                   jnp.asarray(s_valid), jnp.asarray(s_rank.astype(i32)),
-                   jnp.asarray(node_row[s_m].astype(i32)))
-    (feasible, k_first, j_last, take, sp_ok, sp_pos, sp_jmax,
-     sp_nserv) = (np.asarray(o)[:J] for o in out)
+    with _ob.span("solver.tables") if _ob.enabled else _obs.NO_SPAN:
+        # ---- per-job gather tables (host; identical scalar math) -----------
+        B = bucket_size(J)
+        jt = _job_tables(jobs, ps, now, utility, B)
+        W, single, Kj, pref = jt.W, jt.single, jt.Kj, jt.pref
+        x_sorted, u_tab = jt.x_sorted, jt.u_tab
+        rank, usable, x_key = jt.rank, jt.usable, jt.x_key
 
-    # ---- costs and payoffs: host-exact, in the reference's order -------
-    # consolidated: per (node, rank) the key's Eq. 5 prefix at its take,
-    # summed over the job's usable ranks like the reference's (N, K) sum
-    Jr = np.arange(J)[:, None]
-    rk, us, u_j = rank[:J], usable[:J], u_tab[:J]
-    t_key = np.where(us, take[Jr, node_row, np.minimum(rk, R - 1)], 0)
-    vs = np.zeros((J, N, R + 1))            # column R: unusable keys
-    vs[Jr, node_row, rk] = np.where(us, cumP[np.arange(M), t_key], 0.0)
-    packed_cost = np.zeros((J, N))
-    for kj in np.unique(Kj[:J]):
-        rows = np.nonzero(Kj[:J] == kj)[0]
-        packed_cost[rows] = np.ascontiguousarray(
-            vs[rows, :, :int(kj)]).sum(axis=-1)
-    packed_payoff = u_j[Jr, j_last] - packed_cost
-    # spread: the chosen units' prices summed in pool order, one gang
-    # size at a time so each row sums exactly like the reference's 1-D
-    # np.sum over W elements, plus the communication penalty
-    sp_cost = np.zeros((J, R))
-    Wj = W[:J].astype(np.intp)
-    for w in np.unique(Wj):
-        rows = np.nonzero(Wj == w)[0]
-        sp_cost[rows] = s_price[rows[:, None, None],
-                                sp_pos[rows, :, :w]].sum(axis=-1)
-    u_jmax = u_j[Jr, np.maximum(sp_jmax, 0)]
-    sp_cost = np.where(sp_nserv > 1,
-                       sp_cost + COMM_COST_FRAC * np.maximum(u_jmax, 0.0)
-                       * (sp_nserv - 1), sp_cost)
-    sp_pay = u_jmax - sp_cost
+        # ---- shared price tables (host NumPy: bitwise Eq. 5 prefixes) ------
+        P = ps.unit_prices(np.asarray(gamma, dtype=float), C)
+        cumP = np.zeros((M, C + 1))
+        np.cumsum(P, axis=1, out=cumP[:, 1:])
 
-    # ---- winner selection in the reference enumeration order -----------
-    # flat candidate axis, per job: for each preference prefix k=1..R,
-    # the N consolidated node slots (a node is live under its *first*
-    # feasible prefix only), then the prefix's spread slot; np.argmax's
-    # first-maximum matches the reference's strict-> scan.
-    pay = np.full((J, R * (N + 1)), -np.inf)
-    for k in range(1, R + 1):
-        base = (k - 1) * (N + 1)
-        live = feasible & (k_first == k - 1)
-        pay[:, base:base + N] = np.where(live, packed_payoff, -np.inf)
-        pay[:, base + N] = np.where(sp_ok[:, k - 1], sp_pay[:, k - 1],
-                                    -np.inf)
-    pay[Kj[:J] == 0] = -np.inf
-    win = np.argmax(pay, axis=1)
-    win_pay = pay[np.arange(J), win]
+        # ---- batched stable sort of the spread pool (host: NumPy's
+        # mergesort is the reference op and beats XLA's CPU sort) -----------
+        avf = np.asarray(avail, dtype=float)
+        unit_ok = np.arange(C)[None, :] < avf[:, None]          # (M, C)
+        valid = usable[:, :, None] & unit_ok[None, :, :]        # (B, M, C)
+        ratio = np.where(valid, P[None, :, :] / x_key[:, :, None], np.inf)
+        order = np.argsort(ratio.reshape(B, M * C), axis=-1, kind="stable")
+        s_m = order // C                                        # pool key
+        s_rank = np.take_along_axis(rank, s_m, axis=1)
+        s_valid = np.take_along_axis(valid.reshape(B, -1), order, axis=-1)
+        s_price = P.reshape(-1)[order]
+        node_row = np.asarray(ps.node_row)
+        wmax = _spread_width(W[:J])
+        i32 = np.int32
+        # the kernel's job tables, uploaded on every call
+        host = (W.astype(i32), Kj.astype(i32), rank.astype(i32), single,
+                s_valid, s_rank.astype(i32), node_row[s_m].astype(i32))
+    with _ob.span("solver.device") if _ob.enabled else _obs.NO_SPAN:
+        kern = _get_kernel(N, R, wmax)
+        if _ob.enabled:
+            _ob.count("solver_batch_calls")
+            # one XLA compilation per distinct dispatch-shape tuple
+            _ob.kernel_shape((N, R, wmax, B, M, C))
+            _ob.count("solver.h2d_bytes", sum(a.nbytes for a in host) + (
+                avf.nbytes if avail_dev is None else 0))
+        with enable_x64():
+            avail_d = avail_dev if avail_dev is not None \
+                else jnp.asarray(avf)
+            out = kern(avail_d, ps.device_view("node_row"),
+                       *(jnp.asarray(a) for a in host))
+        (feasible, k_first, j_last, take, sp_ok, sp_pos, sp_jmax,
+         sp_nserv) = (np.asarray(o)[:J] for o in out)
 
-    # ---- winner materialization -----------------------------------------
-    found = win_pay > -np.inf
-    kb, slot = np.divmod(win, N + 1)
-    results: List = [None] * J
-    node_ids = [n.node_id for n in ps.cluster.nodes]
+    with _ob.span("solver.finish") if _ob.enabled else _obs.NO_SPAN:
+        # ---- costs and payoffs: host-exact, in the reference's order -------
+        # consolidated: per (node, rank) the key's Eq. 5 prefix at its take,
+        # summed over the job's usable ranks like the reference's (N, K) sum
+        Jr = np.arange(J)[:, None]
+        rk, us, u_j = rank[:J], usable[:J], u_tab[:J]
+        t_key = np.where(us, take[Jr, node_row, np.minimum(rk, R - 1)], 0)
+        vs = np.zeros((J, N, R + 1))            # column R: unusable keys
+        vs[Jr, node_row, rk] = np.where(us, cumP[np.arange(M), t_key], 0.0)
+        packed_cost = np.zeros((J, N))
+        for kj in np.unique(Kj[:J]):
+            rows = np.nonzero(Kj[:J] == kj)[0]
+            packed_cost[rows] = np.ascontiguousarray(
+                vs[rows, :, :int(kj)]).sum(axis=-1)
+        packed_payoff = u_j[Jr, j_last] - packed_cost
+        # spread: the chosen units' prices summed in pool order, one gang
+        # size at a time so each row sums exactly like the reference's 1-D
+        # np.sum over W elements, plus the communication penalty
+        sp_cost = np.zeros((J, R))
+        Wj = W[:J].astype(np.intp)
+        for w in np.unique(Wj):
+            rows = np.nonzero(Wj == w)[0]
+            sp_cost[rows] = s_price[rows[:, None, None],
+                                    sp_pos[rows, :, :w]].sum(axis=-1)
+        u_jmax = u_j[Jr, np.maximum(sp_jmax, 0)]
+        sp_cost = np.where(sp_nserv > 1,
+                           sp_cost + COMM_COST_FRAC * np.maximum(u_jmax, 0.0)
+                           * (sp_nserv - 1), sp_cost)
+        sp_pay = u_jmax - sp_cost
 
-    if _ob.enabled:
-        # runner-up provenance (repro.obs.explain): masked second argmax
-        # over the same candidate axis — matches the per-job path's
-        # second-best tracking, including first-maximum tie handling
-        pay2 = pay.copy()
-        pay2[np.arange(J), win] = -np.inf
-        win2 = np.argmax(pay2, axis=1)
-        win2_pay = pay2[np.arange(J), win2]
-        k2, slot2 = np.divmod(win2, N + 1)
+        # ---- winner selection in the reference enumeration order -----------
+        # flat candidate axis, per job: for each preference prefix k=1..R,
+        # the N consolidated node slots (a node is live under its *first*
+        # feasible prefix only), then the prefix's spread slot; np.argmax's
+        # first-maximum matches the reference's strict-> scan.
+        pay = np.full((J, R * (N + 1)), -np.inf)
+        for k in range(1, R + 1):
+            base = (k - 1) * (N + 1)
+            live = feasible & (k_first == k - 1)
+            pay[:, base:base + N] = np.where(live, packed_payoff, -np.inf)
+            pay[:, base + N] = np.where(sp_ok[:, k - 1], sp_pay[:, k - 1],
+                                        -np.inf)
+        pay[Kj[:J] == 0] = -np.inf
+        win = np.argmax(pay, axis=1)
+        win_pay = pay[np.arange(J), win]
 
-        def _ru_of(j: int) -> Optional[dict]:
-            if not win2_pay[j] > -np.inf:
-                return None
-            s2 = int(slot2[j])
-            if s2 < N:
-                return {"kind": "pack", "node": node_ids[s2],
+        # ---- winner materialization -----------------------------------------
+        found = win_pay > -np.inf
+        kb, slot = np.divmod(win, N + 1)
+        results: List = [None] * J
+        node_ids = [n.node_id for n in ps.cluster.nodes]
+
+        if _ob.decisions is not None:
+            # runner-up provenance (repro.obs.explain): masked second argmax
+            # over the same candidate axis — matches the per-job path's
+            # second-best tracking, including first-maximum tie handling
+            pay2 = pay.copy()
+            pay2[np.arange(J), win] = -np.inf
+            win2 = np.argmax(pay2, axis=1)
+            win2_pay = pay2[np.arange(J), win2]
+            k2, slot2 = np.divmod(win2, N + 1)
+
+            def _ru_of(j: int) -> Optional[dict]:
+                if not win2_pay[j] > -np.inf:
+                    return None
+                s2 = int(slot2[j])
+                if s2 < N:
+                    return {"kind": "pack", "node": node_ids[s2],
+                            "payoff": float(win2_pay[j])}
+                kp = int(k2[j]) + 1
+                return {"kind": "spread", "prefix": kp,
+                        "n_servers": int(sp_nserv[j, kp - 1]),
                         "payoff": float(win2_pay[j])}
-            kp = int(k2[j]) + 1
-            return {"kind": "spread", "prefix": kp,
-                    "n_servers": int(sp_nserv[j, kp - 1]),
-                    "payoff": float(win2_pay[j])}
-    else:
-        def _ru_of(j: int) -> Optional[dict]:
-            return None
+        else:
+            def _ru_of(j: int) -> Optional[dict]:
+                return None
 
-    for j in np.nonzero(found)[0].tolist():
-        h, k = int(slot[j]), int(kb[j]) + 1
-        if h < N:
-            payoff, cost = packed_payoff[j, h], packed_cost[j, h]
-            rate = x_sorted[j, j_last[j, h]]
-        else:
-            payoff, cost = sp_pay[j, k - 1], sp_cost[j, k - 1]
-            rate = x_sorted[j, sp_jmax[j, k - 1]]
-        if payoff <= 0 and not force:        # mu_j <= 0 (lines 29-33)
-            continue
-        if h < N:
-            tk = take[j, h]
-            alloc = {(node_ids[h], gtypes[pref[j, kk]]): int(tk[kk])
-                     for kk in range(int(Kj[j])) if tk[kk] > 0}
-        else:
-            counts = np.bincount(s_m[j, sp_pos[j, k - 1, :Wj[j]]],
-                                 minlength=M)
-            alloc = {ps.keys[m]: int(counts[m])
-                     for m in np.nonzero(counts)[0]}
-        results[j] = Candidate(alloc, float(cost), float(payoff),
-                               float(rate), runner_up=_ru_of(j))
-    from repro.analysis import invariants as _inv
-    if _inv.sanitize_enabled():
-        for job, cand in zip(jobs, results):
-            if cand is not None:
-                _inv.check_candidate(job.job_id, job.n_workers,
-                                     cand.alloc, cand.payoff, cand.cost,
-                                     forced=force,
-                                     context="(find_alloc_batch)")
-    if details:
-        det = BatchDetails(
-            avail0=avf.copy(), cumP=cumP, u_tab=u_j, rank=rk, usable=us,
-            Kj=Kj[:J], W=Wj, single=single[:J], feasible=feasible,
-            k_first=k_first, packed_payoff=packed_payoff, sp_ok=sp_ok,
-            sp_cost=sp_cost, sp_pay=sp_pay, sp_jmax=sp_jmax,
-            sp_nserv=sp_nserv, sp_pos=sp_pos, s_m=s_m[:J], found=found,
-            win_pay=win_pay, kb=kb, slot=slot, node_row=node_row)
-        return results, det
-    return results
+        for j in np.nonzero(found)[0].tolist():
+            h, k = int(slot[j]), int(kb[j]) + 1
+            if h < N:
+                payoff, cost = packed_payoff[j, h], packed_cost[j, h]
+                rate = x_sorted[j, j_last[j, h]]
+            else:
+                payoff, cost = sp_pay[j, k - 1], sp_cost[j, k - 1]
+                rate = x_sorted[j, sp_jmax[j, k - 1]]
+            if payoff <= 0 and not force:        # mu_j <= 0 (lines 29-33)
+                continue
+            if h < N:
+                tk = take[j, h]
+                alloc = {(node_ids[h], gtypes[pref[j, kk]]): int(tk[kk])
+                         for kk in range(int(Kj[j])) if tk[kk] > 0}
+            else:
+                counts = np.bincount(s_m[j, sp_pos[j, k - 1, :Wj[j]]],
+                                     minlength=M)
+                alloc = {ps.keys[m]: int(counts[m])
+                         for m in np.nonzero(counts)[0]}
+            results[j] = Candidate(alloc, float(cost), float(payoff),
+                                   float(rate), runner_up=_ru_of(j))
+        from repro.analysis import invariants as _inv
+        if _inv.sanitize_enabled():
+            for job, cand in zip(jobs, results):
+                if cand is not None:
+                    _inv.check_candidate(job.job_id, job.n_workers,
+                                         cand.alloc, cand.payoff, cand.cost,
+                                         forced=force,
+                                         context="(find_alloc_batch)")
+        if details:
+            det = BatchDetails(
+                avail0=avf.copy(), cumP=cumP, u_tab=u_j, rank=rk, usable=us,
+                Kj=Kj[:J], W=Wj, single=single[:J], feasible=feasible,
+                k_first=k_first, packed_payoff=packed_payoff, sp_ok=sp_ok,
+                sp_cost=sp_cost, sp_pay=sp_pay, sp_jmax=sp_jmax,
+                sp_nserv=sp_nserv, sp_pos=sp_pos, s_m=s_m[:J], found=found,
+                win_pay=win_pay, kb=kb, slot=slot, node_row=node_row)
+            return results, det
+        return results
 
 
 # --------------------------------------------------------------------------
@@ -965,147 +967,162 @@ def _scan_prefix(jobs: List, avail: np.ndarray, gamma: np.ndarray,
     depth = (np.asarray(gamma, dtype=float)
              + np.asarray(avail, dtype=float)).max(initial=1.0)
     C = int(max(ps.cap_arr.max(initial=1.0), depth, 1.0))
-    B = bucket_size(J)
-    jt = _job_tables(jobs, ps, now, utility, B)
-    # Eq. 5 gather table: gamma is integer-valued on the greedy path and
-    # every *used* unit index satisfies gamma + i < cap, so P_tab rows
-    # are bitwise the reference's unit_prices(gamma) at every scan step;
-    # keys with equal rows price identically at equal gamma
-    P_tab = ps.unit_prices(np.zeros(M), C)
-    prow = np.unique(P_tab, axis=0, return_inverse=True)[1].reshape(-1)
-    node_row = np.asarray(ps.node_row)
-
-    # fixed per-job spread-pool order over the whole (key, unit) table
-    # (gamma-independent — see the kernel docstring): NumPy's stable
-    # mergesort is the reference sort, computed once per scan
-    L = M * C
-    ratio_tab = np.where(jt.usable[:, :, None],
-                         P_tab[None, :, :] / jt.x_key[:, :, None],
-                         np.inf)
-    order = np.argsort(ratio_tab.reshape(B, L), axis=-1, kind="stable")
-    s_m = (order // C).astype(np.int32)
-    s_u = (order % C).astype(np.int32)
-    s_rank = np.take_along_axis(jt.rank, s_m, axis=1).astype(np.int32)
-    s_price = P_tab.reshape(-1)[order]
-    s_node = node_row[s_m].astype(np.int32)
-
-    wmax = _spread_width(jt.W[:J])
-    kern = _get_commit_kernel(N, R, COMM_COST_FRAC, wmax)
     _ob = _obs.get()
-    if _ob.enabled:
-        _ob.count("solver_scan_calls")
-        _ob.observe("solver.scan_jobs", J)
-        # one XLA compile per distinct (geometry, carry/xs shape) tuple
-        _ob.kernel_shape(("commit_scan", N, R, COMM_COST_FRAC, B, M, C,
-                          wmax))
-    i32 = np.int32
-    with enable_x64():
-        # fresh uploads: the kernel donates these carry buffers
-        free0 = jnp.asarray(np.asarray(avail).astype(i32))
-        gamma0 = jnp.asarray(np.asarray(gamma).astype(i32))
-        out = kern(free0, gamma0, jnp.asarray(P_tab),
-                   ps.device_view("node_row"), jnp.asarray(prow.astype(i32)),
-                   jnp.asarray(jt.W.astype(i32)),
-                   jnp.asarray(jt.Kj.astype(i32)),
-                   jnp.asarray(jt.single),
-                   jnp.asarray(jt.rank.astype(i32)),
-                   jnp.asarray(jt.u_tab), jnp.asarray(s_m),
-                   jnp.asarray(s_u), jnp.asarray(s_rank),
-                   jnp.asarray(s_price), jnp.asarray(s_node))
-    (free_f, gamma_f, won, win, counts, win2, win2_pay, sp_nserv,
-     sure) = map(np.asarray, out)
-    n_ok = int(np.argmin(sure[:J])) if not sure[:J].all() else J
+    with _ob.span("solver.tables") if _ob.enabled else _obs.NO_SPAN:
+        B = bucket_size(J)
+        jt = _job_tables(jobs, ps, now, utility, B)
+        # Eq. 5 gather table: gamma is integer-valued on the greedy path and
+        # every *used* unit index satisfies gamma + i < cap, so P_tab rows
+        # are bitwise the reference's unit_prices(gamma) at every scan step;
+        # keys with equal rows price identically at equal gamma
+        P_tab = ps.unit_prices(np.zeros(M), C)
+        prow = np.unique(P_tab, axis=0, return_inverse=True)[1].reshape(-1)
+        node_row = np.asarray(ps.node_row)
 
-    node_ids = [n.node_id for n in ps.cluster.nodes]
-    gam_run = np.asarray(gamma, dtype=np.int64).copy()
-    want_ru = _ob.enabled
-    for p in range(n_ok):
-        if not won[p]:
-            continue
-        cnts = counts[p]
-        ms = np.nonzero(cnts)[0]
-        kbp, slotp = divmod(int(win[p]), N + 1)
-        ru = None
-        if want_ru and win2_pay[p] > -np.inf:
-            k2, s2 = divmod(int(win2[p]), N + 1)
-            if s2 < N:
-                ru = {"kind": "pack", "node": node_ids[s2],
-                      "payoff": float(win2_pay[p])}
+        # fixed per-job spread-pool order over the whole (key, unit) table
+        # (gamma-independent — see the kernel docstring): NumPy's stable
+        # mergesort is the reference sort, computed once per scan
+        L = M * C
+        ratio_tab = np.where(jt.usable[:, :, None],
+                             P_tab[None, :, :] / jt.x_key[:, :, None],
+                             np.inf)
+        order = np.argsort(ratio_tab.reshape(B, L), axis=-1, kind="stable")
+        s_m = (order // C).astype(np.int32)
+        s_u = (order % C).astype(np.int32)
+        s_rank = np.take_along_axis(jt.rank, s_m, axis=1).astype(np.int32)
+        s_price = P_tab.reshape(-1)[order]
+        s_node = node_row[s_m].astype(np.int32)
+
+        wmax = _spread_width(jt.W[:J])
+        i32 = np.int32
+        # the scan's operands but the cached node_row view, uploaded on
+        # every call in argument order around it
+        carry = (np.asarray(avail).astype(i32), np.asarray(gamma).astype(i32))
+        head = (P_tab,)
+        tail = (prow.astype(i32), jt.W.astype(i32), jt.Kj.astype(i32),
+                jt.single, jt.rank.astype(i32), jt.u_tab, s_m, s_u, s_rank,
+                s_price, s_node)
+    with _ob.span("solver.device") if _ob.enabled else _obs.NO_SPAN:
+        kern = _get_commit_kernel(N, R, COMM_COST_FRAC, wmax)
+        if _ob.enabled:
+            _ob.count("solver_scan_calls")
+            _ob.observe("solver.scan_jobs", J)
+            # one XLA compile per distinct (geometry, carry/xs shape) tuple
+            _ob.kernel_shape(("commit_scan", N, R, COMM_COST_FRAC, B, M, C,
+                              wmax))
+            _ob.count("solver.h2d_bytes",
+                      sum(a.nbytes for a in carry + head + tail))
+        with enable_x64():
+            # fresh uploads: the kernel donates the carry buffers
+            out = kern(*(jnp.asarray(a) for a in carry + head),
+                       ps.device_view("node_row"),
+                       *(jnp.asarray(a) for a in tail))
+        (free_f, gamma_f, won, win, counts, win2, win2_pay, sp_nserv,
+         sure) = map(np.asarray, out)
+    with _ob.span("solver.finish") if _ob.enabled else _obs.NO_SPAN:
+        n_ok = int(np.argmin(sure[:J])) if not sure[:J].all() else J
+
+        node_ids = [n.node_id for n in ps.cluster.nodes]
+        gam_run = np.asarray(gamma, dtype=np.int64).copy()
+        want_ru = _ob.decisions is not None
+        for p in range(n_ok):
+            if not won[p]:
+                continue
+            cnts = counts[p]
+            ms = np.nonzero(cnts)[0]
+            kbp, slotp = divmod(int(win[p]), N + 1)
+            ru = None
+            if want_ru and win2_pay[p] > -np.inf:
+                k2, s2 = divmod(int(win2[p]), N + 1)
+                if s2 < N:
+                    ru = {"kind": "pack", "node": node_ids[s2],
+                          "payoff": float(win2_pay[p])}
+                else:
+                    ru = {"kind": "spread", "prefix": k2 + 1,
+                          "n_servers": int(sp_nserv[p, k2]),
+                          "payoff": float(win2_pay[p])}
+            jl = int(jt.rank[p, ms].max())      # slowest rank actually used
+            if slotp < N:
+                # consolidated: cost = sum over preference ranks of the
+                # key's sequential unit-price prefix (np.cumsum order);
+                # ps.keys[m] is the reference's (node_id, gpu_type) tuple
+                cost = 0.0
+                alloc = {}
+                for m in ms[np.argsort(jt.rank[p, ms], kind="stable")]:
+                    g = int(gam_run[m])
+                    cnt = int(cnts[m])
+                    cost += float(np.cumsum(P_tab[m, g:g + cnt])[-1])
+                    alloc[ps.keys[m]] = cnt
             else:
-                ru = {"kind": "spread", "prefix": k2 + 1,
-                      "n_servers": int(sp_nserv[p, k2]),
-                      "payoff": float(win2_pay[p])}
-        jl = int(jt.rank[p, ms].max())      # slowest rank actually used
-        if slotp < N:
-            # consolidated: cost = sum over preference ranks of the
-            # key's sequential unit-price prefix (np.cumsum order);
-            # ps.keys[m] is the reference's (node_id, gpu_type) tuple
-            cost = 0.0
-            alloc = {}
-            for m in ms[np.argsort(jt.rank[p, ms], kind="stable")]:
-                g = int(gam_run[m])
-                cnt = int(cnts[m])
-                cost += float(np.cumsum(P_tab[m, g:g + cnt])[-1])
-                alloc[ps.keys[m]] = cnt
-        else:
-            unit_m = np.repeat(ms, cnts[ms])
-            unit_i = np.concatenate([np.arange(cnts[m]) for m in ms])
-            prices = P_tab[unit_m, gam_run[unit_m] + unit_i]
-            # reference summation order == stable sort of the chosen
-            # units by (ratio, flat index)
-            o = np.lexsort((unit_m * C + unit_i,
-                            prices / jt.x_key[p, unit_m]))
-            cost = float(prices[o].sum())
-            nserv = int(np.unique(node_row[ms]).size)
-            if nserv > 1:
-                cost += COMM_COST_FRAC * max(jt.u_tab[p, jl], 0.0) \
-                    * (nserv - 1)
-            alloc = {ps.keys[m]: int(cnts[m]) for m in ms}
-        payoff = float(jt.u_tab[p, jl] - cost)
-        results[jobs[p].job_id] = Candidate(alloc, float(cost), payoff,
-                                            float(jt.x_sorted[p, jl]),
-                                            runner_up=ru)
-        gam_run[ms] += cnts[ms]
+                unit_m = np.repeat(ms, cnts[ms])
+                unit_i = np.concatenate([np.arange(cnts[m]) for m in ms])
+                prices = P_tab[unit_m, gam_run[unit_m] + unit_i]
+                # reference summation order == stable sort of the chosen
+                # units by (ratio, flat index)
+                o = np.lexsort((unit_m * C + unit_i,
+                                prices / jt.x_key[p, unit_m]))
+                cost = float(prices[o].sum())
+                nserv = int(np.unique(node_row[ms]).size)
+                if nserv > 1:
+                    cost += COMM_COST_FRAC * max(jt.u_tab[p, jl], 0.0) \
+                        * (nserv - 1)
+                alloc = {ps.keys[m]: int(cnts[m]) for m in ms}
+            payoff = float(jt.u_tab[p, jl] - cost)
+            results[jobs[p].job_id] = Candidate(alloc, float(cost), payoff,
+                                                float(jt.x_sorted[p, jl]),
+                                                runner_up=ru)
+            gam_run[ms] += cnts[ms]
 
-    total = counts[:n_ok].sum(axis=0)
-    avail -= total
-    gamma += total
-    from repro.analysis import invariants as _inv
-    if _inv.sanitize_enabled():
-        # the donated-carry outputs must agree with the host accounting
-        # when every step was accepted (all quantities are integers)
-        if n_ok == J and not np.array_equal(
-                free_f.astype(float), np.asarray(avail, dtype=float)):
-            _inv.violate("conservation",
-                         "scan carry free_arr diverged from host delta",
-                         max_err=float(np.abs(free_f
-                                              - np.asarray(avail)).max()))
-        for job in jobs[:n_ok]:
-            cand = results.get(job.job_id)
-            if cand is not None:
-                _inv.check_candidate(job.job_id, job.n_workers,
-                                     cand.alloc, cand.payoff, cand.cost,
-                                     context="(scan_commit)")
-    return n_ok
+        total = counts[:n_ok].sum(axis=0)
+        avail -= total
+        gamma += total
+        from repro.analysis import invariants as _inv
+        if _inv.sanitize_enabled():
+            # the donated-carry outputs must agree with the host accounting
+            # when every step was accepted (all quantities are integers)
+            if n_ok == J and not np.array_equal(
+                    free_f.astype(float), np.asarray(avail, dtype=float)):
+                _inv.violate("conservation",
+                             "scan carry free_arr diverged from host delta",
+                             max_err=float(np.abs(free_f
+                                                  - np.asarray(avail)).max()))
+            for job in jobs[:n_ok]:
+                cand = results.get(job.job_id)
+                if cand is not None:
+                    _inv.check_candidate(job.job_id, job.n_workers,
+                                         cand.alloc, cand.payoff, cand.cost,
+                                         context="(scan_commit)")
+        return n_ok
+
+
+def _dispatch(ob, jobs: List, avail: np.ndarray, gamma: np.ndarray, ps,
+              now: float, utility, avail_dev, threshold: Optional[int]):
+    """``find_alloc_batch`` with details, in a ``solver_dispatch``
+    span."""
+    with (ob.span("solver_dispatch", backend="jax", n_jobs=len(jobs),
+                  threshold=threshold, bucket=bucket_size(len(jobs)))
+          if ob.enabled else _obs.NO_SPAN) as sp:
+        cands, det = find_alloc_batch(jobs, avail, gamma, ps, now, utility,
+                                      avail_dev=avail_dev, details=True)
+        if ob.enabled:
+            sp.set(candidates=sum(1 for c in cands if c is not None))
+    return cands, det
 
 
 def commit_greedy(queue: List, avail: np.ndarray, gamma: np.ndarray,
-                  ps, now: float, utility, avail_dev=None) -> Dict:
+                  ps, now: float, utility, avail_dev=None,
+                  threshold: Optional[int] = None) -> Dict:
     """The greedy pass of ``dp_allocation`` without per-job host
     round-trips: one fused pricing dispatch ranks all standalone
     winners, conflict-free waves commit in aggregated deltas, and the
     conflicting remainder runs through the device-side scan.  Mutates
     ``avail``/``gamma`` in place and returns ``{job_id: Candidate}``
     bit-identical to the sequential NumPy loop (the equivalence
-    oracle kept verbatim in ``repro.core.dp``)."""
+    oracle kept verbatim in ``repro.core.dp``).  ``threshold`` is the
+    crossover that routed the queue here, for the trace only."""
     _ob = _obs.get()
-    b_us = _ob.begin() if _ob.enabled else 0.0
-    cands, det = find_alloc_batch(queue, avail, gamma, ps, now, utility,
-                                  avail_dev=avail_dev, details=True)
-    if _ob.enabled:
-        _ob.end("solver_dispatch", b_us, backend="jax",
-                queue_len=len(queue), bucket=bucket_size(len(queue)),
-                candidates=sum(1 for c in cands if c is not None))
+    cands, det = _dispatch(_ob, queue, avail, gamma, ps, now, utility,
+                           avail_dev, threshold)
     # payoff *density* order (per requested device), ties in queue order
     # — identical to the sequential loop's sort
     dens = [(c.payoff / max(1, j.n_workers), i)
@@ -1116,8 +1133,9 @@ def commit_greedy(queue: List, avail: np.ndarray, gamma: np.ndarray,
     cur_jobs = queue
     key_index = ps.key_index
     while rows:
-        accepted, consumed, tv = _wave_accepts(det, cands, rows,
-                                               key_index)
+        with _ob.span("solver.waves") if _ob.enabled else _obs.NO_SPAN:
+            accepted, consumed, tv = _wave_accepts(det, cands, rows,
+                                                   key_index)
         if _ob.enabled:
             _ob.count("solver.commit_waves")
             _ob.observe("solver.wave_size", consumed)
@@ -1136,13 +1154,8 @@ def commit_greedy(queue: List, avail: np.ndarray, gamma: np.ndarray,
             chosen.update(_scan_commit(rest, avail, gamma, ps, now,
                                        utility))
             break
-        b_us = _ob.begin() if _ob.enabled else 0.0
-        cands, det = find_alloc_batch(rest, avail, gamma, ps, now,
-                                      utility, details=True)
-        if _ob.enabled:
-            _ob.end("solver_dispatch", b_us, backend="jax",
-                    queue_len=len(rest), bucket=bucket_size(len(rest)),
-                    candidates=sum(1 for c in cands if c is not None))
+        cands, det = _dispatch(_ob, rest, avail, gamma, ps, now, utility,
+                               None, threshold)
         cur_jobs = rest
         rows = list(range(len(rest)))
     return chosen

@@ -63,8 +63,8 @@ class Candidate:
     payoff: float
     rate: float      # bottleneck iterations/sec (x_j)
     # allocation provenance (repro.obs): the second-best candidate in the
-    # FIND_ALLOC enumeration and its payoff.  Populated only while an
-    # observer is installed; excluded from equality/repr so it can never
+    # FIND_ALLOC enumeration and its payoff.  Populated only while a
+    # decision log is open; excluded from equality/repr so it can never
     # participate in a decision comparison.
     runner_up: Optional[dict] = dataclasses.field(
         default=None, compare=False, repr=False)
@@ -199,8 +199,8 @@ def _find_alloc_arrays(job: Job, avail: np.ndarray, gamma: np.ndarray,
     # prefix's spread candidate; first maximum wins on ties).  Runner-up
     # tracking (want_ru) is provenance-only: it observes the same scan
     # without touching the winner comparison, so decisions are identical
-    # with observability on or off.
-    want_ru = _obs.get().enabled
+    # with or without a decision log, the only reader.
+    want_ru = _obs.get().decisions is not None
     best_payoff = -np.inf
     best = None                      # ("pack", node_row) | ("spread", k)
     ru_payoff = -np.inf
@@ -266,25 +266,25 @@ def _scan_standalone(queue: List[Job], avail0: np.ndarray,
                      free_is_ps: bool) -> List[Optional[Candidate]]:
     """Standalone candidate per queued job against one shared state —
     one fused device call on the jax backend, a per-job loop otherwise."""
-    from repro.core.batch_solver import bucket_size, use_batch
+    from repro.core.batch_solver import bucket_size, crossover, use_batch
 
     _ob = _obs.get()
     batched = use_batch(solver, len(queue))
-    b_us = _ob.begin() if _ob.enabled else 0.0
-    if batched:
-        from repro.core.batch_solver import find_alloc_batch
-        dev = ps.device_view("free") if free_is_ps else None
-        out = find_alloc_batch(queue, avail0, gamma0, ps, now, utility,
-                               avail_dev=dev)
-    else:
-        out = [_find_alloc_arrays(j, avail0, gamma0, ps, now, utility,
-                                  force=False) for j in queue]
-    if _ob.enabled:
-        _ob.end("solver_dispatch", b_us,
-                backend="jax" if batched else "numpy",
-                queue_len=len(queue),
-                bucket=bucket_size(len(queue)) if batched else None,
-                candidates=sum(1 for c in out if c is not None))
+    with (_ob.span("solver_dispatch",
+                   backend="jax" if batched else "numpy",
+                   n_jobs=len(queue), threshold=crossover(solver),
+                   bucket=bucket_size(len(queue)) if batched else None)
+          if _ob.enabled else _obs.NO_SPAN) as sp:
+        if batched:
+            from repro.core.batch_solver import find_alloc_batch
+            dev = ps.device_view("free") if free_is_ps else None
+            out = find_alloc_batch(queue, avail0, gamma0, ps, now, utility,
+                                   avail_dev=dev)
+        else:
+            out = [_find_alloc_arrays(j, avail0, gamma0, ps, now, utility,
+                                      force=False) for j in queue]
+        if _ob.enabled:
+            sp.set(candidates=sum(1 for c in out if c is not None))
     return out
 
 
@@ -308,68 +308,14 @@ def _sanitize_selection(sel: Dict[int, "Candidate"], queue: List[Job],
     _inv.check_selection(sel, free_map, "(dp_allocation)")
 
 
-def dp_allocation(queue: List[Job],
-                  free: Optional[Dict[Tuple[int, str], int]],
-                  ps: PriceState, now: float, utility: UtilityFn,
-                  max_exact: int = 64,
-                  solver: Optional[str] = None,
-                  sanitize: bool = None) -> Dict[int, Candidate]:
-    """Select jobs + allocations maximizing total payoff (Algorithm 2).
-
-    Exact select/skip DP with memoization for queues up to ``max_exact``;
-    longer queues are processed in payoff-sorted greedy chunks (the paper
-    handles 2048-job rounds in <7 min by incrementally allocating new jobs
-    only — same spirit).  The greedy path keeps the cluster state as
-    arrays and commits winners incrementally — no per-job dict rebuild.
-
-    ``solver`` picks the backend for the queue-wide candidate scans (see
-    module docstring); on the jax backend the greedy commit itself runs
-    through ``batch_solver.commit_greedy`` (conflict-free waves + a
-    device-side scan over the conflicting remainder), while the NumPy
-    path keeps the sequential re-solve loop — the bitwise equivalence
-    oracle — so decisions are backend-independent."""
-    from repro.analysis import invariants as _inv
-    _san = _inv.sanitize_enabled(sanitize)
-    free_is_ps = free is None
-    if len(queue) > max_exact:
-        avail0 = ps.free_arr.copy() if free_is_ps else ps.free_to_arr(free)
-        avail_init = avail0.copy() if _san else None
-        gamma0 = ps.gamma_arr.copy()
-        from repro.core.batch_solver import use_commit
-        if use_commit(solver, len(queue)):
-            from repro.core.batch_solver import commit_greedy
-            dev = ps.device_view("free") if free_is_ps else None
-            chosen: Dict[int, Candidate] = commit_greedy(
-                queue, avail0, gamma0, ps, now, utility, avail_dev=dev)
-            if _san:
-                _sanitize_selection(chosen, queue, ps, avail_init)
-            return chosen
-        # greedy pass: highest standalone payoff first
-        cands = _scan_standalone(queue, avail0, gamma0, ps, now, utility,
-                                 solver, free_is_ps)
-        # payoff *density* (per requested device): lets several
-        # small jobs beat one large one under contention
-        order = [(c.payoff / max(1, j.n_workers), j)
-                 for j, c in zip(queue, cands) if c]
-        order.sort(key=lambda t: -t[0])
-        chosen = {}
-        avail = avail0
-        gamma = gamma0
-        # sequential commit: re-solve each winner at the accumulated
-        # state (the device commit path's bitwise equivalence oracle)
-        for _, j in order:
-            c = _find_alloc_arrays(j, avail, gamma, ps, now, utility,
-                                   force=False)
-            if c:
-                chosen[j.job_id] = c
-                for k, v in c.alloc.items():
-                    m = ps.key_index[k]
-                    avail[m] -= v
-                    gamma[m] += v
-        if _san:
-            _sanitize_selection(chosen, queue, ps, avail_init)
-        return chosen
-
+def _exact_dp(queue: List[Job],
+              free: Optional[Dict[Tuple[int, str], int]],
+              ps: PriceState, now: float, utility: UtilityFn,
+              solver: Optional[str],
+              free_is_ps: bool) -> Dict[int, Candidate]:
+    """The exact select/skip DP with memoization (Algorithm 2 for
+    queues up to ``max_exact``): the selection of largest total
+    payoff."""
     memo: Dict = {}
 
     # the all-skip spine of the DP evaluates every job once at the empty
@@ -412,7 +358,77 @@ def dp_allocation(queue: List[Job],
         memo[k] = (best_v, best_sel)
         return memo[k]
 
-    _, sel = rec(0, {})
+    return rec(0, {})[1]
+
+
+def dp_allocation(queue: List[Job],
+                  free: Optional[Dict[Tuple[int, str], int]],
+                  ps: PriceState, now: float, utility: UtilityFn,
+                  max_exact: int = 64,
+                  solver: Optional[str] = None,
+                  sanitize: bool = None) -> Dict[int, Candidate]:
+    """Select jobs + allocations maximizing total payoff (Algorithm 2).
+
+    Exact select/skip DP with memoization for queues up to ``max_exact``;
+    longer queues are processed in payoff-sorted greedy chunks (the paper
+    handles 2048-job rounds in <7 min by incrementally allocating new jobs
+    only — same spirit).  The greedy path keeps the cluster state as
+    arrays and commits winners incrementally — no per-job dict rebuild.
+
+    ``solver`` picks the backend for the queue-wide candidate scans (see
+    module docstring); on the jax backend the greedy commit itself runs
+    through ``batch_solver.commit_greedy`` (conflict-free waves + a
+    device-side scan over the conflicting remainder), while the NumPy
+    path keeps the sequential re-solve loop — the bitwise equivalence
+    oracle — so decisions are backend-independent."""
+    from repro.analysis import invariants as _inv
+    _san = _inv.sanitize_enabled(sanitize)
+    free_is_ps = free is None
+    if len(queue) > max_exact:
+        avail0 = ps.free_arr.copy() if free_is_ps else ps.free_to_arr(free)
+        avail_init = avail0.copy() if _san else None
+        gamma0 = ps.gamma_arr.copy()
+        from repro.core.batch_solver import use_commit
+        if use_commit(solver, len(queue)):
+            from repro.core.batch_solver import commit_greedy, crossover
+            dev = ps.device_view("free") if free_is_ps else None
+            chosen: Dict[int, Candidate] = commit_greedy(
+                queue, avail0, gamma0, ps, now, utility, avail_dev=dev,
+                threshold=crossover(solver, commit=True)
+                if _obs.get().enabled else None)
+            if _san:
+                _sanitize_selection(chosen, queue, ps, avail_init)
+            return chosen
+        # greedy pass: highest standalone payoff first
+        cands = _scan_standalone(queue, avail0, gamma0, ps, now, utility,
+                                 solver, free_is_ps)
+        # payoff *density* (per requested device): lets several
+        # small jobs beat one large one under contention
+        order = [(c.payoff / max(1, j.n_workers), j)
+                 for j, c in zip(queue, cands) if c]
+        order.sort(key=lambda t: -t[0])
+        chosen = {}
+        avail = avail0
+        gamma = gamma0
+        # sequential commit: re-solve each winner at the accumulated
+        # state (the device commit path's bitwise equivalence oracle)
+        for _, j in order:
+            c = _find_alloc_arrays(j, avail, gamma, ps, now, utility,
+                                   force=False)
+            if c:
+                chosen[j.job_id] = c
+                for k, v in c.alloc.items():
+                    m = ps.key_index[k]
+                    avail[m] -= v
+                    gamma[m] += v
+        if _san:
+            _sanitize_selection(chosen, queue, ps, avail_init)
+        return chosen
+
+    _ob = _obs.get()
+    with (_ob.span("dp.exact", queue_len=len(queue)) if _ob.enabled
+          else _obs.NO_SPAN):
+        sel = _exact_dp(queue, free, ps, now, utility, solver, free_is_ps)
     if _san:
         avail_chk = (ps.free_arr.copy() if free_is_ps
                      else ps.free_to_arr(free))

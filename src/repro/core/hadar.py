@@ -70,6 +70,32 @@ class HadarScheduler(Scheduler):
             now, job.job_id, job.n_workers, phase, self.solver, rows,
             cand.cost, cand.payoff, cand.rate, cand.runner_up))
 
+    def _backfill(self, queue, out, extra, ps, now, ob, log) -> None:
+        """Work-conserving backfill: waiting jobs onto idle devices,
+        best payoff first.  The reference prices against (pre-selection
+        free) - extra; extra is exactly the allocations committed since
+        the kept jobs, so that difference *is* the live free_arr — no
+        dict."""
+        for j in sorted(queue, key=lambda j: (j.arrival, j.job_id)):
+            if j.job_id in out:
+                continue
+            avail = ps.free_arr.copy()
+            gamma = ps.gamma_arr.copy()
+            for k, v in extra.items():      # seed double-count kept
+                m = ps.key_index.get(k)
+                if m is not None:
+                    gamma[m] += v
+            cand = _find_alloc_arrays(j, avail, gamma, ps, now,
+                                      self.utility, force=True)
+            if cand is None:
+                continue
+            out[j.job_id] = cand.alloc
+            if log:
+                self._log_decision(ob, now, j, cand, ps, "backfill")
+            ps.commit(cand.alloc)
+            for k, v in cand.alloc.items():
+                extra[k] = extra.get(k, 0) + v
+
     def schedule(self, now, round_len, jobs, cluster):
         _ob = _obs.get()
         sw = _obs.StopWatch().start()
@@ -104,23 +130,25 @@ class HadarScheduler(Scheduler):
         # one aggregated free/gamma delta (and one sanitizer pass)
         ps.commit_batch(j.alloc for j in kept)
 
-        b_us = _ob.begin() if _ob.enabled else 0.0
-        sel = dp_allocation(queue, None, ps, now, self.utility,
-                            max_exact=self.max_exact_dp,
-                            solver=self.solver)
-        if _ob.enabled:
-            _ob.end("hadar.dp", b_us, t=now, queue_len=len(queue),
-                    selected=len(sel), full_pass=full_pass)
-            by_id = {j.job_id: j for j in queue}
+        with (_ob.span("hadar.dp", t=now, queue_len=len(queue),
+                       full_pass=full_pass)
+              if _ob.enabled else _obs.NO_SPAN) as sp:
+            sel = dp_allocation(queue, None, ps, now, self.utility,
+                                max_exact=self.max_exact_dp,
+                                solver=self.solver)
+            if _ob.enabled:
+                sp.set(selected=len(sel))
         extra: Dict = {}
         for jid, cand in sel.items():
             out[jid] = cand.alloc
             for k, v in cand.alloc.items():
                 extra[k] = extra.get(k, 0) + v
-        if _ob.enabled:
+        log = _ob.decisions is not None
+        if log:
             # decision provenance snapshots each winner's Eq. 5 prices
-            # at its *pre-commit* gamma, so the obs path keeps the
+            # at its *pre-commit* gamma, so a decision log keeps the
             # sequential log-then-commit interleaving
+            by_id = {j.job_id: j for j in queue}
             for jid, cand in sel.items():
                 self._log_decision(_ob, now, by_id[jid], cand, ps, "dp")
                 ps.commit(cand.alloc)
@@ -128,29 +156,9 @@ class HadarScheduler(Scheduler):
             ps.commit_batch(cand.alloc for cand in sel.values())
 
         if self.work_conserving:
-            # backfill: waiting jobs onto idle devices, best payoff first.
-            # The reference prices against (pre-selection free) - extra;
-            # extra is exactly the allocations committed since the kept
-            # jobs, so that difference *is* the live free_arr — no dict.
-            for j in sorted(queue, key=lambda j: (j.arrival, j.job_id)):
-                if j.job_id in out:
-                    continue
-                avail = ps.free_arr.copy()
-                gamma = ps.gamma_arr.copy()
-                for k, v in extra.items():      # seed double-count kept
-                    m = ps.key_index.get(k)
-                    if m is not None:
-                        gamma[m] += v
-                cand = _find_alloc_arrays(j, avail, gamma, ps, now,
-                                          self.utility, force=True)
-                if cand is None:
-                    continue
-                out[j.job_id] = cand.alloc
-                if _ob.enabled:
-                    self._log_decision(_ob, now, j, cand, ps, "backfill")
-                ps.commit(cand.alloc)
-                for k, v in cand.alloc.items():
-                    extra[k] = extra.get(k, 0) + v
+            with (_ob.span("hadar.backfill") if _ob.enabled
+                  else _obs.NO_SPAN):
+                self._backfill(queue, out, extra, ps, now, _ob, log)
 
         self.last_sched_seconds = sw.stop()
         if _ob.enabled:

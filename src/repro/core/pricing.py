@@ -221,7 +221,11 @@ class PriceState:
             raise KeyError(f"no device view named {name!r}")
         if name in self._dirty or name not in self._dev:
             from repro.core.batch_solver import to_device
-            self._dev[name] = to_device(getattr(self, self._VIEWS[name]))
+            host = getattr(self, self._VIEWS[name])
+            _ob = _obs.get()
+            if _ob.enabled:
+                _ob.count("solver.h2d_bytes", host.nbytes)
+            self._dev[name] = to_device(host)
             self._dirty.discard(name)
         return self._dev[name]
 
@@ -234,23 +238,23 @@ class PriceState:
         reset, and every array object keeps its identity (the event
         engine's cached device buffers stay valid until dirtied)."""
         _ob = _obs.get()
-        b_us = _ob.begin() if _ob.enabled else 0.0
-        self.u_max.clear()
-        self.u_min.clear()
-        self._compute_bounds(jobs, now)
-        self.umin_arr[:] = [self.u_min[r] for (_, r) in self.keys]
-        self.umax_arr[:] = [self.u_max[r] for (_, r) in self.keys]
-        np.divide(self.umax_arr, self.umin_arr, out=self.q_arr)
-        self._in_managed_op = True
-        try:
-            self.gamma.clear()              # zeroes gamma_arr in place
-        finally:
-            self._in_managed_op = False
-        self.free_arr[:] = self.cap_arr
-        self._conserved = True              # clean slate: gamma+free==cap
-        self._touch("umin", "umax", "q", "free")
+        with (_ob.span("pricestate.refresh", jobs=len(jobs), now=now)
+              if _ob.enabled else _obs.NO_SPAN):
+            self.u_max.clear()
+            self.u_min.clear()
+            self._compute_bounds(jobs, now)
+            self.umin_arr[:] = [self.u_min[r] for (_, r) in self.keys]
+            self.umax_arr[:] = [self.u_max[r] for (_, r) in self.keys]
+            np.divide(self.umax_arr, self.umin_arr, out=self.q_arr)
+            self._in_managed_op = True
+            try:
+                self.gamma.clear()          # zeroes gamma_arr in place
+            finally:
+                self._in_managed_op = False
+            self.free_arr[:] = self.cap_arr
+            self._conserved = True          # clean slate: gamma+free==cap
+            self._touch("umin", "umax", "q", "free")
         if _ob.enabled:
-            _ob.end("pricestate.refresh", b_us, jobs=len(jobs), now=now)
             _ob.count("pricestate_refreshes")
         if self._sanitize:
             _inv.check_price_state(self, "after refresh")
@@ -287,7 +291,7 @@ class PriceState:
     def commit(self, alloc: Dict[Tuple[int, str], int]) -> None:
         _ob = _obs.get()
         if _ob.enabled:
-            _ob.price_op("commit", len(alloc))
+            _ob.count("pricestate_commits")
         if self._sanitize:
             _inv.check_commit_amounts(self, alloc, "commit")
         self._in_managed_op = True
@@ -317,8 +321,7 @@ class PriceState:
             return
         _ob = _obs.get()
         if _ob.enabled:
-            _ob.price_op("commit_batch",
-                         sum(len(a) for a in allocs))
+            _ob.count("pricestate_commit_batchs")
             _ob.observe("pricing.commit_batch_size", len(allocs))
         total: Dict[Tuple[int, str], int] = {}
         for alloc in allocs:
@@ -342,7 +345,7 @@ class PriceState:
     def release(self, alloc: Dict[Tuple[int, str], int]) -> None:
         _ob = _obs.get()
         if _ob.enabled:
-            _ob.price_op("release", len(alloc))
+            _ob.count("pricestate_releases")
         if self._sanitize:
             _inv.check_commit_amounts(self, alloc, "release")
             if self._conserved:

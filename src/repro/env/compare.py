@@ -112,23 +112,24 @@ def run_one(name: str, jobs: List[Job], cluster: Cluster,
                          f"{sorted(POLICIES)}")
     run_jobs = clone_jobs(jobs)
     ob = _obs.get()
-    b_us = ob.begin() if ob.enabled else 0.0
-    if name == "hadare":
-        from repro.sim.adapters import simulate_hadare
-        res = simulate_hadare(run_jobs, cluster, round_len=round_len,
-                              faults=faults, solver=solver,
-                              sanitize=sanitize,
-                              **{k: v for k, v in kw.items()
-                                 if k in ("max_rounds", "n_copies",
-                                          "sync_overhead")})
-    else:
-        from repro.sim.adapters import run as run_engine
-        res = run_engine(POLICIES[name](), run_jobs, cluster, mode=mode,
-                         round_len=round_len, faults=faults,
-                         solver=solver, sanitize=sanitize, **kw)
-    if ob.enabled:
-        ob.end("compare.policy", b_us, policy=name, mode=mode,
-               ttd=res.total_seconds, evictions=res.evictions)
+    with (ob.span("compare.policy", policy=name, mode=mode) if ob.enabled
+          else _obs.NO_SPAN) as sp:
+        if name == "hadare":
+            from repro.sim.adapters import simulate_hadare
+            res = simulate_hadare(run_jobs, cluster, round_len=round_len,
+                                  faults=faults, solver=solver,
+                                  sanitize=sanitize,
+                                  **{k: v for k, v in kw.items()
+                                     if k in ("max_rounds", "n_copies",
+                                              "sync_overhead")})
+        else:
+            from repro.sim.adapters import run as run_engine
+            res = run_engine(POLICIES[name](), run_jobs, cluster,
+                             mode=mode, round_len=round_len,
+                             faults=faults, solver=solver,
+                             sanitize=sanitize, **kw)
+        if ob.enabled:
+            sp.set(ttd=res.total_seconds, evictions=res.evictions)
     return res
 
 

@@ -20,14 +20,23 @@ Activation:
   an observer to a block and writes its outputs on exit.
 
 What gets recorded (see README "Observability" for the full catalogue):
-scheduler-consult latency spans + histogram, solver dispatches (backend,
-bucket, queue length), PriceState commit/release/refresh, event-queue
-pops, per-interval sim-time spans, HadarE consolidation points, jax
-kernel (re)compiles, free capacity per (node, GPU-type), and the
-per-decision provenance log (``repro.obs.explain``).
+scheduler-consult latency spans + histogram, spans of the consult's
+phases (DP, backfill, solver table building, device wait, finish, wave
+walk, exact DP) and of the engine between consults, solver dispatches
+(backend, bucket, job count, crossover), host-to-device bytes,
+PriceState commit/release/refresh counts, event-queue pops,
+per-interval sim-time spans, HadarE consolidation points, jax kernel
+(re)compiles, free capacity per (node, GPU-type), and the per-decision
+provenance log (``repro.obs.explain``).  Every wall span also enters a
+``jax.profiler.TraceAnnotation``, so a profiler trace taken meanwhile
+shows it on its host plane.
 
 Decisions are **bit-identical** with observability on or off — hooks
 only read scheduler state (pinned by ``tests/test_obs_integration.py``).
+Provenance (runner-up tracking, per-winner logging) is the only work
+that changes the host code path, and it runs only while a decision log
+is open: a session with ``decisions=False`` schedules exactly as with
+observability off.
 """
 from __future__ import annotations
 
@@ -113,6 +122,66 @@ class _ConsultTimer(StopWatch):
                 "engine": self._engine, "wall_ms": self.seconds * 1e3})
 
 
+_TraceAnnotation = None
+
+
+class Span:
+    """One wall-track span (a complete ``X`` event on the observer's
+    recorder, when it has one) that also enters a
+    ``jax.profiler.TraceAnnotation`` of the same name, so a profiler
+    trace holds every program span on its host plane, stamped by the
+    profiler's own clock.  ``set`` adds args before the span closes;
+    ``open``/``close`` serve a span that no block can hold."""
+
+    __slots__ = ("_trace", "name", "args", "_us0", "_ann")
+
+    def __init__(self, trace: Optional[TraceRecorder], name: str,
+                 args: dict):
+        self._trace = trace
+        self.name = name
+        self.args = args
+        self._us0 = 0.0
+        self._ann = None
+
+    def set(self, **args) -> None:
+        self.args.update(args)
+
+    def open(self) -> "Span":
+        global _TraceAnnotation
+        if _TraceAnnotation is None:
+            from jax.profiler import TraceAnnotation as _TraceAnnotation
+        self._ann = _TraceAnnotation(self.name)
+        self._ann.__enter__()
+        if self._trace is not None:
+            self._us0 = self._trace.now()
+        return self
+
+    def close(self) -> None:
+        if self._trace is not None:
+            self._trace.complete(self.name, self._us0, self.args)
+        self._ann.__exit__(None, None, None)
+
+    __enter__ = open
+
+    def __exit__(self, *exc) -> None:
+        self.close()
+
+
+class _NoSpan:
+    """The span of a disabled observer: enters and records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+NO_SPAN = _NoSpan()
+
+
 class NullObserver:
     """Disabled observability: every hook is a no-op.  Hook sites guard
     anything that would build arguments on ``enabled``, so this class
@@ -159,17 +228,11 @@ class Observer:
                 queue_len: int = 0) -> _ConsultTimer:
         return _ConsultTimer(self, engine, scheduler, t, queue_len)
 
-    def begin(self) -> float:
-        """Open a wall span; pair with :meth:`end`."""
-        return self.trace.now() if self.trace is not None else 0.0
-
-    def end(self, name: str, start_us: float, **args) -> None:
-        if self.trace is not None:
-            self.trace.complete(name, start_us, args)
-
-    def instant(self, name: str, **args) -> None:
-        if self.trace is not None:
-            self.trace.instant(name, args)
+    def span(self, name: str, **args) -> "Span":
+        """A wall span over a block: ``with ob.span(name, **args) as
+        sp:``.  Hook sites guard it on ``enabled`` and use
+        :data:`NO_SPAN` otherwise."""
+        return Span(self.trace, name, args)
 
     def sim_span(self, name: str, t0: float, t1: float, **args) -> None:
         if self.trace is not None:
@@ -182,10 +245,6 @@ class Observer:
     def count(self, name: str, n: int = 1) -> None:
         if self.metrics is not None:
             self.metrics.counter(name).inc(n)
-
-    def gauge(self, name: str, v: float) -> None:
-        if self.metrics is not None:
-            self.metrics.gauge(name).set(v)
 
     def observe(self, name: str, v: float) -> None:
         if self.metrics is not None:
@@ -242,13 +301,6 @@ class Observer:
                 float(rec.get("lost_gpu_seconds", 0.0)))
         if self.decisions is not None:
             self.decisions.record(rec)
-
-    def price_op(self, op: str, n_keys: int) -> None:
-        """PriceState commit/release accounting."""
-        if self.metrics is not None:
-            self.metrics.counter(f"pricestate_{op}s").inc()
-        if self.trace is not None:
-            self.trace.instant(f"pricestate.{op}", {"keys": n_keys})
 
     def free_capacity(self, keys, free_arr) -> None:
         """Per-(node, GPU-type) free-device gauges from a PriceState."""
@@ -352,7 +404,8 @@ _install_from_env()
 
 __all__ = [
     "Counter", "DecisionLog", "Gauge", "Histogram", "MetricsRegistry",
-    "NullObserver", "Observer", "StopWatch", "TraceRecorder",
+    "NO_SPAN", "NullObserver", "Observer", "Span", "StopWatch",
+    "TraceRecorder",
     "decision_record", "enabled", "eviction_record", "explain_allocation",
     "get", "install",
     "session", "validate_trace",

@@ -161,6 +161,7 @@ def simulate_hadare(jobs: List[Job], cluster: Cluster,
     rounds: List[RoundRecord] = []
     t = 0.0
     rnd = 0
+    step = None     # engine.step: round work between two consults
     while rnd < max_rounds:
         if bool(np.all(total - done <= 1e-9)):
             break
@@ -215,6 +216,9 @@ def simulate_hadare(jobs: List[Job], cluster: Cluster,
         # the consult covers schedule + sibling dedupe, matching the
         # seed's sched_seconds accounting
         if view.nodes:
+            if step is not None:
+                step.close()
+                step = None
             with _ob.consult("hadare", sched.name, t, qlen) as sw:
                 desired = sched.schedule(t, round_len, live, view)
                 n_raw = len(desired) if _ob.enabled else 0
@@ -225,6 +229,8 @@ def simulate_hadare(jobs: List[Job], cluster: Cluster,
             n_raw = 0
             sched_s = 0.0
         if _ob.enabled:
+            if step is None:
+                step = _ob.span("engine.step").open()
             _ob.sim_instant("hadare.consolidation", t, raw=n_raw,
                             kept=len(desired), copies=len(live))
 
@@ -396,6 +402,8 @@ def simulate_hadare(jobs: List[Job], cluster: Cluster,
         t += skip * round_len
         rnd += skip
 
+    if step is not None:
+        step.close()
     total_s = max((p.finish_time or t) for p in parents) if parents else 0.0
     res = SimResult("hadare", rounds, parents, total_s,
                     gpu_seconds_busy=busy_total,

@@ -486,14 +486,12 @@ def event_stream(jobs: List[Job], cluster: Cluster,
                                 running, n_active - running,
                                 open_changed, open_sched_s)
 
+    step = None     # engine.step: from a decision to the next consult
     while q and n_events < max_events:
-        if _ob.enabled:
-            b_us = _ob.begin()
+        with _ob.span("event_pop") if _ob.enabled else _obs.NO_SPAN as sp:
             batch = q.pop_batch()
-            _ob.end("event_pop", b_us, n=len(batch),
-                    t=batch[0].time if batch else None)
-        else:
-            batch = q.pop_batch()
+            if _ob.enabled:
+                sp.set(n=len(batch), t=batch[0].time if batch else None)
         if not batch:
             break
         t_new = batch[0].time
@@ -604,6 +602,9 @@ def event_stream(jobs: List[Job], cluster: Cluster,
         if view.nodes:
             qlen = sum(1 for j in jobs if not j.is_done()
                        and j.arrival <= t and j.alloc is None)
+            if step is not None:
+                step.close()
+                step = None
             sent = yield ConsultPoint(
                 t=t, round_len=round_len, jobs=jobs, view=view,
                 completed=completed_since, queue_len=qlen,
@@ -612,6 +613,8 @@ def event_stream(jobs: List[Job], cluster: Cluster,
                 avail_gpu_seconds=recorder.avail_gpu_seconds,
                 lost_gpu_seconds=recorder.lost_gpu_seconds,
                 evictions=recorder.evictions)
+            if _ob.enabled:
+                step = _ob.span("engine.step").open()
             desired, open_sched_s = _parse_action(sent)
             completed_since = []
             sched_calls += 1
@@ -670,6 +673,8 @@ def event_stream(jobs: List[Job], cluster: Cluster,
                         and (not stable or j.alloc is None) for j in jobs)):
             q.push_reschedule(t + round_len)
 
+    if step is not None:
+        step.close()
     total = max((j.finish_time or t) for j in jobs) if jobs else 0.0
     return recorder.result(name, jobs, total, n_events, sched_calls)
 

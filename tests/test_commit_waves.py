@@ -289,13 +289,29 @@ def test_use_commit_dispatch_rules(monkeypatch):
 
 
 def test_resolve_backend_logs_crossover(monkeypatch):
+    """An ``auto`` dispatch on each side of the pricing crossover: the
+    ``solver_dispatch`` span carries the backend it ran on, the job
+    count and the threshold that decided it."""
     monkeypatch.delenv(ENV_THRESHOLD, raising=False)
-    with obs.session(trace=False, decisions=False) as ob:
-        backend = resolve_backend("auto", 10_000)
-    assert backend == "jax"
-    summ = ob.metrics.summary()
-    assert summ["gauges"].get("solver.auto_min_jobs") \
-        == solver_threshold()
+    thr = solver_threshold()
+    assert thr < commit_threshold()      # both sides take the scan path
+    rng = np.random.RandomState(11)
+    cluster = _random_cluster(rng)
+    args = {}
+    for n in (thr - 1, thr):
+        jobs = _random_jobs(cluster, rng, n)
+        ps = PriceState(cluster, jobs, HORIZON, effective_throughput, 0.0)
+        with obs.session(decisions=False) as ob:
+            dp_allocation(jobs, None, ps, 0.0, effective_throughput,
+                          max_exact=0, solver="auto")
+        spans = [e for e in ob.trace.events
+                 if e["name"] == "solver_dispatch"]
+        assert len(spans) == 1
+        args[n] = spans[0]["args"]
+        assert args[n]["n_jobs"] == n and args[n]["threshold"] == thr
+        assert args[n]["backend"] == resolve_backend("auto", n)
+    assert args[thr - 1]["backend"] == "numpy"
+    assert args[thr]["backend"] == "jax"
 
 
 def test_engine_rejects_unknown_solver():
