@@ -11,6 +11,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional
 
+import numpy as np
+
 from repro import obs as _obs
 from repro.core.dp import _find_alloc_arrays, dp_allocation
 from repro.core.pricing import PriceState
@@ -70,15 +72,24 @@ class HadarScheduler(Scheduler):
             now, job.job_id, job.n_workers, phase, self.solver, rows,
             cand.cost, cand.payoff, cand.rate, cand.runner_up))
 
-    def _backfill(self, queue, out, extra, ps, now, ob, log) -> None:
-        """Work-conserving backfill: waiting jobs onto idle devices,
-        best payoff first.  The reference prices against (pre-selection
-        free) - extra; extra is exactly the allocations committed since
-        the kept jobs, so that difference *is* the live free_arr — no
-        dict."""
-        for j in sorted(queue, key=lambda j: (j.arrival, j.job_id)):
-            if j.job_id in out:
+    def _backfill(self, queue, out, extra, ps, now, ob, log):
+        """Work-conserving backfill: waiting jobs onto idle devices, in
+        queue order (``schedule`` sorts it by arrival, then id).  The
+        reference prices against (pre-selection free) - extra; extra is
+        exactly the allocations committed since the kept jobs, so that
+        difference *is* the live free_arr — no dict.  Jobs the
+        :class:`_FitGate` proves cannot fit are skipped unpriced; free
+        devices only shrink here, so none is priced once none is free.
+        Returns the counts of jobs priced and skipped."""
+        pending = [j for j in queue if j.job_id not in out]
+        gate = _FitGate(ps)
+        priced = 0
+        for j in pending:
+            if gate.exhausted:
+                break
+            if not gate.may_fit(j):
                 continue
+            priced += 1
             avail = ps.free_arr.copy()
             gamma = ps.gamma_arr.copy()
             for k, v in extra.items():      # seed double-count kept
@@ -95,6 +106,8 @@ class HadarScheduler(Scheduler):
             ps.commit(cand.alloc)
             for k, v in cand.alloc.items():
                 extra[k] = extra.get(k, 0) + v
+            gate.refresh()
+        return priced, len(pending) - priced
 
     def schedule(self, now, round_len, jobs, cluster):
         _ob = _obs.get()
@@ -157,10 +170,52 @@ class HadarScheduler(Scheduler):
 
         if self.work_conserving:
             with (_ob.span("hadar.backfill") if _ob.enabled
-                  else _obs.NO_SPAN):
-                self._backfill(queue, out, extra, ps, now, _ob, log)
+                  else _obs.NO_SPAN) as sp:
+                priced, skipped = self._backfill(queue, out, extra, ps,
+                                                 now, _ob, log)
+                if _ob.enabled:
+                    sp.set(priced=priced, skipped=skipped)
+                    _ob.count("backfill.priced", priced)
+                    _ob.count("backfill.skipped", skipped)
 
         self.last_sched_seconds = sw.stop()
         if _ob.enabled:
             _ob.free_capacity(ps.keys, ps.free_arr)
         return out
+
+
+class _FitGate:
+    """Exact feasibility gate for the backfill's FIND_ALLOC calls.
+
+    With ``force`` FIND_ALLOC returns None exactly when the job has no
+    consolidated candidate (W free usable devices on one node) and no
+    spread candidate (W free usable devices in all; a single-node HadarE
+    copy has none), usable meaning a type of throughput > 0.  Both
+    counts are bounded by the positive free devices of those types, so
+    a job whose bound is below W gets None whether priced or not.  The
+    bounds are cached per usable-type set until the next commit."""
+
+    def __init__(self, ps: PriceState):
+        self.ps = ps
+        self.types = ps.cluster.gpu_types
+        self.refresh()
+
+    def refresh(self) -> None:
+        """Re-read ``ps.free_arr``; call after every commit."""
+        ps = self.ps
+        self.node_free = np.zeros((ps.n_node_rows, len(self.types)))
+        self.node_free[ps.node_row, ps.type_col] = np.maximum(
+            ps.free_arr, 0.0)
+        # no job (W >= 1) fits once no device is free
+        self.exhausted = not self.node_free.any()
+        self._bounds = {}         # usable cols -> (in all, on one node)
+
+    def may_fit(self, job: Job) -> bool:
+        cols = tuple(c for c, r in enumerate(self.types)
+                     if job.throughput.get(r, 0) > 0)
+        bound = self._bounds.get(cols)
+        if bound is None:
+            per_node = self.node_free[:, list(cols)].sum(axis=1)
+            bound = self._bounds[cols] = (per_node.sum(),
+                                          per_node.max(initial=0.0))
+        return bound[1 if job.single_node else 0] >= job.n_workers
